@@ -23,6 +23,7 @@ from repro.core import (HostParallelExecutor, SequentialExecutor, adaptive,
                         make_chunks, when_all)
 from repro.core.calibration import CalibrationCache
 from repro.core.model import DecisionKey, ExecutionModel
+from repro.launch.compile_cache import place_compile_cache
 
 N_CHUNKS = 16
 REPEATS = 200
@@ -162,6 +163,7 @@ def main() -> None:
                          "BENCH_decision_engine.json")
     ap.add_argument("--out", default=DEFAULT_OUT)
     args = ap.parse_args()
+    place_compile_cache()
 
     print("name,us_per_call,derived")
     rows, report = _bench_all(smoke=args.smoke)

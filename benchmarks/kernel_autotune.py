@@ -40,6 +40,7 @@ from repro.core.calibration import CalibrationCache  # noqa: E402
 from repro.kernels import ops as kops  # noqa: E402
 from repro.kernels import tuning  # noqa: E402
 from repro.kernels.autotune import KernelTuner  # noqa: E402
+from repro.launch.compile_cache import place_compile_cache  # noqa: E402
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
@@ -134,6 +135,7 @@ def main() -> None:
     ap.add_argument("--fresh", action="store_true",
                     help="delete the calibration file first (force search)")
     args = ap.parse_args()
+    place_compile_cache()
 
     if args.fresh and os.path.exists(args.cal_file):
         os.remove(args.cal_file)

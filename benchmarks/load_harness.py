@@ -59,6 +59,7 @@ from repro.configs import get_config  # noqa: E402
 from repro.core.acc import AdaptiveCoreChunk, StaticCoreChunk  # noqa: E402
 from repro.core.adaptive import adaptive  # noqa: E402
 from repro.core.executor import SequentialExecutor  # noqa: E402
+from repro.launch.compile_cache import place_compile_cache  # noqa: E402
 from repro.models import lm  # noqa: E402
 from repro.serve import (GENERATORS, QueueFullError,  # noqa: E402
                          ServeFrontend, ServeScheduler, SLOModel,
@@ -324,6 +325,7 @@ def main() -> int:
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_load.json"))
     args = ap.parse_args()
+    place_compile_cache()
 
     kinds = (args.traces.split(",") if args.traces
              else (["heavy", "shared_prefix"] if args.smoke

@@ -16,7 +16,11 @@ def main() -> None:
                     help="model-based figures only (fast)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import place_compile_cache
+
     from . import executor_overhead, figures
+
+    place_compile_cache()
 
     suites = [
         ("executor API v2 + decision-engine overhead (empty tasks; "

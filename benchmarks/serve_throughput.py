@@ -60,6 +60,7 @@ from repro.core.acc import AdaptiveCoreChunk, StaticCoreChunk  # noqa: E402
 from repro.core.adaptive import adaptive  # noqa: E402
 from repro.core.executor import SequentialExecutor  # noqa: E402
 from repro.core.hardware import TPU_V5E  # noqa: E402
+from repro.launch.compile_cache import place_compile_cache  # noqa: E402
 from repro.models import lm  # noqa: E402
 from repro.serve import (ServeScheduler, materialize,  # noqa: E402
                          percentile, templated_trace, trace_summary)
@@ -321,6 +322,7 @@ def main() -> int:
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_serve.json"))
     args = ap.parse_args()
+    place_compile_cache()
 
     n_requests = args.requests or (8 if args.smoke else 16)
     new_tokens = args.new_tokens or (24 if args.smoke else 48)

@@ -37,14 +37,7 @@ from ..core.policy import ExecutionPolicy
 __all__ = ["Plan", "plan", "measured_body", "run_map_chunks",
            "run_reduce_chunks", "mesh_executor_of", "submesh_1d",
            "pad_to", "mesh_map", "mesh_map_with_left_halo", "mesh_scan",
-           "mesh_reduce", "shard_map"]
-
-# jax.shard_map landed in 0.4.35 as experimental and moved to the top
-# level later; support both spellings.  Public: the algorithm modules (and
-# any out-of-tree mesh backend) should use this instead of jax.shard_map.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map
+           "mesh_reduce"]
 
 
 @dataclasses.dataclass
@@ -162,8 +155,8 @@ def mesh_map(mexec: MeshExecutor, cores: int, shard_fn: Callable,
     """Elementwise map via shard_map over an acc-chosen sub-mesh."""
     mesh = submesh_1d(mexec, cores)
     xp, n = pad_to(x, cores, fill)
-    f = jax.jit(shard_map(shard_fn, mesh=mesh,
-                          in_specs=P("data"), out_specs=P("data")))
+    f = jax.jit(jax.shard_map(shard_fn, mesh=mesh,
+                              in_specs=P("data"), out_specs=P("data")))
     return f(xp)[:n]
 
 
@@ -183,8 +176,8 @@ def mesh_map_with_left_halo(mexec: MeshExecutor, cores: int,
             last, "data", [(i, (i + 1) % cores) for i in range(cores)])
         return shard_fn(xl, left, idx)
 
-    f = jax.jit(shard_map(wrapper, mesh=mesh,
-                          in_specs=P("data"), out_specs=P("data")))
+    f = jax.jit(jax.shard_map(wrapper, mesh=mesh,
+                              in_specs=P("data"), out_specs=P("data")))
     return f(xp)[:n]
 
 
@@ -205,8 +198,8 @@ def mesh_scan(mexec: MeshExecutor, cores: int, x: jax.Array,
         offset = local_total(jnp.where(mask, totals, identity))
         return apply_offset(scanned, offset)
 
-    f = jax.jit(shard_map(wrapper, mesh=mesh,
-                          in_specs=P("data"), out_specs=P("data")))
+    f = jax.jit(jax.shard_map(wrapper, mesh=mesh,
+                              in_specs=P("data"), out_specs=P("data")))
     return f(xp)[:n]
 
 
@@ -222,6 +215,6 @@ def mesh_reduce(mexec: MeshExecutor, cores: int, x: jax.Array,
         p = local_partial(xl)
         return jnp.reshape(p, (1,) + p.shape)
 
-    f = jax.jit(shard_map(wrapper, mesh=mesh,
-                          in_specs=P("data"), out_specs=P("data")))
+    f = jax.jit(jax.shard_map(wrapper, mesh=mesh,
+                              in_specs=P("data"), out_specs=P("data")))
     return f(xp)

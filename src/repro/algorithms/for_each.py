@@ -38,7 +38,7 @@ def transform(policy, x: jax.Array, fn: Callable,
 
         xp, n = detail.pad_to(x, p.cores)
         yp, _ = detail.pad_to(y, p.cores)
-        f = jax.jit(detail.shard_map(
+        f = jax.jit(jax.shard_map(
             jf, mesh=mesh, in_specs=(P("data"), P("data")),
             out_specs=P("data")))
         return f(xp, yp)[:n]

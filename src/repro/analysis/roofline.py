@@ -145,13 +145,7 @@ def model_flops(cfg: ArchConfig, shape: ShapeConfig) -> float:
 def analyze(compiled, *, cfg: ArchConfig, shape: ShapeConfig,
             mesh_name: str, chips: int,
             hw: HardwareSpec = TPU_V5E) -> RooflineReport:
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jax: list of per-program dicts
-        ca = ca[0] if ca else {}
-    flops = float(ca.get("flops", 0.0))
-    byts = float(ca.get("bytes accessed", 0.0))
-    coll = collective_bytes(compiled.as_text())
-    cb = float(coll["bytes"].get("wire_total", 0.0))
+    flops, byts, cb, coll = extract_costs(compiled)
     ma = compiled.memory_analysis()
     peak = float(getattr(ma, "temp_size_in_bytes", 0)
                  + getattr(ma, "output_size_in_bytes", 0))
@@ -173,8 +167,6 @@ def analyze(compiled, *, cfg: ArchConfig, shape: ShapeConfig,
 
 def extract_costs(compiled) -> tuple[float, float, float, dict]:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jax: list of per-program dicts
-        ca = ca[0] if ca else {}
     coll = collective_bytes(compiled.as_text())
     return (float(ca.get("flops", 0.0)),
             float(ca.get("bytes accessed", 0.0)),

@@ -29,7 +29,8 @@ class HardwareSpec:
                      This is the paper's ``T0`` seed; on the host backend it
                      is re-measured at runtime (calibration.py).
     hop_latency:     per-hop latency of the interconnect, seconds.
-    vmem_bytes:      fast scratch per unit (VMEM for TPU, L2 for CPU).
+    vmem_bytes:      fast scratch one kernel may use (the compiler's scoped
+                     VMEM limit for TPU, L2 for CPU).
     """
 
     name: str
@@ -64,7 +65,10 @@ TPU_V5E = HardwareSpec(
     link_bw=50e9,                  # per ICI link
     launch_overhead=5e-6,          # XLA dispatch
     hop_latency=1e-6,              # ICI hop
-    vmem_bytes=128 * 1024 * 1024,  # ~128 MiB VMEM
+    # The scoped VMEM limit a Pallas kernel compiles under by default,
+    # not the chip's physical VMEM: Mosaic refuses any kernel whose
+    # scoped allocation passes it ("Scoped allocation ... limit 16.00M").
+    vmem_bytes=16 * 1024 * 1024,
 )
 
 # --- The paper's two evaluation machines (for figure reproduction) -------

@@ -328,13 +328,11 @@ class MeasuredBlockSearch:
 
         ``eval_context`` restores a clean top-level context (unlike
         ``ensure_compile_time_eval``, it does not leak eager evaluation
-        into the Pallas kernel's own trace); fall back to the latter if
-        a future jax drops it.
+        into the Pallas kernel's own trace).
         """
         import jax
 
-        ctx = getattr(jax.core, "eval_context", None)
-        return ctx() if ctx is not None else jax.ensure_compile_time_eval()
+        return jax.core.eval_context()
 
     def measure(self, run: Callable[..., None], cand: tuple,
                 repeats: int | None = None) -> float:

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tuning import resolve_interpret
+
 
 def _kernel(x_ref, prev_ref, o_ref):
     i = pl.program_id(0)
@@ -29,7 +31,7 @@ def _kernel(x_ref, prev_ref, o_ref):
 
 
 def adjacent_difference_pallas(x: jax.Array, *, block: int,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool | None = None) -> jax.Array:
     """1-d adjacent difference.  ``x`` length must be a multiple of
     ``block`` (ops.py handles padding)."""
     n = x.shape[0]
@@ -44,5 +46,5 @@ def adjacent_difference_pallas(x: jax.Array, *, block: int,
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, x)

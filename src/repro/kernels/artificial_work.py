@@ -9,6 +9,8 @@ import functools
 import jax
 from jax.experimental import pallas as pl
 
+from .tuning import resolve_interpret
+
 
 def _kernel(x_ref, o_ref, *, iters: int):
     x = x_ref[...]
@@ -20,7 +22,7 @@ def _kernel(x_ref, o_ref, *, iters: int):
 
 
 def artificial_work_pallas(x: jax.Array, *, iters: int, block: int,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     n = x.shape[0]
     assert n % block == 0, (n, block)
     return pl.pallas_call(
@@ -29,5 +31,5 @@ def artificial_work_pallas(x: jax.Array, *, iters: int, block: int,
         in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)

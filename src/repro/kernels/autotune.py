@@ -45,8 +45,8 @@ from ..core.calibration import CalibrationCache
 from ..core.hardware import TPU_V5E, HardwareSpec
 from ..core.model import DecisionKey, ExecutionModel, hardware_key
 from . import tuning
-from .tuning import (LANE, SUBLANE, BlockPlan, attention_live_bytes,
-                     max_block_1d)
+from .tuning import (LANE, SUBLANE, TILE_1D, BlockPlan,
+                     attention_live_bytes, max_block_1d)
 
 KEY_NAMESPACE = "pallas_block"
 
@@ -62,7 +62,7 @@ def shape_bucket(n: int) -> int:
 
 def candidates_1d(n: int, *, bytes_per_elem: int = 4,
                   arrays_in_vmem: int = 2, hw: HardwareSpec = TPU_V5E,
-                  align: int = LANE, prior: int | None = None,
+                  align: int = TILE_1D, prior: int | None = None,
                   vmem_fraction: float = 0.25) -> list[int]:
     """Candidate block sizes for a 1-d kernel, analytic prior first.
 
@@ -79,7 +79,8 @@ def candidates_1d(n: int, *, bytes_per_elem: int = 4,
     if prior is None:
         prior = tuning.plan_1d(n, bytes_per_elem=bytes_per_elem,
                                arrays_in_vmem=arrays_in_vmem, hw=hw,
-                               vmem_fraction=vmem_fraction).block
+                               vmem_fraction=vmem_fraction,
+                               align=align).block
 
     def snap(b: int) -> int:
         return min(max((int(b) // align) * align, align), cap)
@@ -208,7 +209,7 @@ class KernelTuner:
     def plan_1d(self, kernel: str, n: int,
                 run: Callable[[int], None], *, dtype="float32",
                 bytes_per_elem: int = 4, arrays_in_vmem: int = 2,
-                align: int = LANE, prior: int | None = None,
+                align: int = TILE_1D, prior: int | None = None,
                 vmem_fraction: float = 0.25) -> BlockPlan:
         """Measured ``BlockPlan`` for a 1-d kernel.
 

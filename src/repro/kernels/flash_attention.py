@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tuning import resolve_interpret
+
 NEG_INF = float("-inf")
 _STAT_LANES = 128  # TPU scratch wants a 128-lane trailing dim
 
@@ -97,7 +99,7 @@ def flash_attention_pallas(
     sq_true: int | None = None,
     block_q: int = 128, block_kv: int = 128,
     plan: tuple[int, int] | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D), Hq % Hkv == 0.
 
@@ -146,16 +148,16 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 
 def _paged_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, scale: float, causal: bool,
-                  hq: int, sq: int, page_size: int, nk: int):
-    g = pl.program_id(0)
+                  hq: int, hkv: int, sq: int, page_size: int, nk: int):
+    b = pl.program_id(0)
     j = pl.program_id(1)
-    b = g // hq
+    group = hq // hkv
 
     @pl.when(j == 0)
     def _init():
@@ -169,11 +171,6 @@ def _paged_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(k_off < kv_len)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[:, 0].astype(jnp.float32)
-        v = v_ref[:, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-
         qi = q_off + jax.lax.broadcasted_iota(
             jnp.int32, (sq, page_size), 0)
         kj = k_off + jax.lax.broadcasted_iota(
@@ -181,31 +178,40 @@ def _paged_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         mask = kj < kv_len
         if causal:
             mask &= qi >= kj
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.where(mask, jnp.exp(s - m_cur[:, None]), 0.0)
-        alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_cur))
-        l_cur = alpha * l_prev + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[...] = jnp.broadcast_to(m_cur[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_cur[:, None], l_ref.shape)
+        for kh in range(hkv):
+            k = k_ref[:, kh, :].astype(jnp.float32)
+            v = v_ref[:, kh, :].astype(jnp.float32)
+            for h in range(kh * group, (kh + 1) * group):
+                # Per head, exactly _kernel's online-softmax step.
+                q = q_ref[0, h].astype(jnp.float32)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ()))) * scale
+                s = jnp.where(mask, s, NEG_INF)
+                m_prev = m_ref[h, :, 0]
+                l_prev = l_ref[h, :, 0]
+                m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+                p = jnp.where(mask, jnp.exp(s - m_cur[:, None]), 0.0)
+                alpha = jnp.where(m_prev == NEG_INF, 0.0,
+                                  jnp.exp(m_prev - m_cur))
+                l_cur = alpha * l_prev + jnp.sum(p, axis=-1)
+                acc_ref[h] = acc_ref[h] * alpha[:, None] + \
+                    jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
+                m_ref[h] = jnp.broadcast_to(m_cur[:, None], (sq, _STAT_LANES))
+                l_ref[h] = jnp.broadcast_to(l_cur[:, None], (sq, _STAT_LANES))
 
     @pl.when(j == nk - 1)
     def _finalize():
-        l = l_ref[:, 0]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        for h in range(hq):
+            l = l_ref[h, :, 0]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, h] = (acc_ref[h] / l_safe[:, None]).astype(o_ref.dtype)
 
 
 def paged_flash_attention_pallas(
     q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     page_table: jax.Array, kv_lens: jax.Array, *,
     page_size: int, causal: bool = True, scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Flash attention reading K/V through a page table.
 
@@ -221,55 +227,57 @@ def paged_flash_attention_pallas(
     operands (``pltpu.PrefetchScalarGridSpec``): the BlockSpec index map
     reads ``page_table[b, j]`` to aim each kv tile's DMA directly at
     its page in HBM — the indirection costs an SMEM lookup, not a
-    gather materialising the contiguous view.  With
-    ``block_kv == page_size`` the tile schedule is *identical* to
-    ``flash_attention_pallas`` over contiguously-laid K/V, so the two
-    are byte-identical — the property the paged pool's hypothesis test
-    pins (tests/test_serve_paged.py).  Pages at or past a lane's length
-    skip their compute via ``pl.when`` — unmapped (scratch) entries are
-    never touched, the structural "don't schedule empty chunks".
+    gather materialising the contiguous view.  The grid is (lane, page):
+    one step DMAs a whole ``(page_size, Hkv, D)`` page, every KV head at
+    once — the block's trailing ``(Hkv, D)`` are the store's own dims,
+    so it is a legal TPU tile at any head count (a one-head
+    ``(page_size, 1, D)`` block is not) — and runs every query head of
+    the lane against it.  Per head the arithmetic is
+    ``flash_attention_pallas``'s with ``block_kv == page_size``, so the
+    two are byte-identical over contiguously-laid K/V — the property
+    the paged pool's hypothesis test pins (tests/test_serve_paged.py).
+    Pages at or past a lane's length skip their compute via
+    ``pl.when`` — unmapped (scratch) entries are never touched, the
+    structural "don't schedule empty chunks".
 
     Sliding windows are unsupported by design: the paged pool rejects
     SWA (a wrapped ring write would straddle shared pages)."""
     b, hq, sq, d = q.shape
     rows, hkv, _ = k_pages.shape
     assert hq % hkv == 0, (hq, hkv)
-    group = hq // hkv
     ps = int(page_size)
     assert rows % ps == 0, (rows, ps)
     nk = int(page_table.shape[1])
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
     kernel = functools.partial(
-        _paged_kernel, scale=scale, causal=causal, hq=hq, sq=sq,
+        _paged_kernel, scale=scale, causal=causal, hq=hq, hkv=hkv, sq=sq,
         page_size=ps, nk=nk)
 
     from jax.experimental.pallas import tpu as pltpu
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b * hq, nk),
+        grid=(b, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, sq, d),
-                         lambda g, j, pt, lens: (g // hq, g % hq, 0, 0)),
-            pl.BlockSpec((ps, 1, d),
-                         lambda g, j, pt, lens:
-                         (pt[g // hq, j], (g % hq) // group, 0)),
-            pl.BlockSpec((ps, 1, d),
-                         lambda g, j, pt, lens:
-                         (pt[g // hq, j], (g % hq) // group, 0)),
+            pl.BlockSpec((1, hq, sq, d),
+                         lambda i, j, pt, lens: (i, 0, 0, 0)),
+            pl.BlockSpec((ps, hkv, d),
+                         lambda i, j, pt, lens: (pt[i, j], 0, 0)),
+            pl.BlockSpec((ps, hkv, d),
+                         lambda i, j, pt, lens: (pt[i, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, sq, d), lambda g, j, pt, lens: (g // hq, g % hq, 0, 0)),
+            (1, hq, sq, d), lambda i, j, pt, lens: (i, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((sq, d), jnp.float32),
-            pltpu.VMEM((sq, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((sq, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((hq, sq, d), jnp.float32),
+            pltpu.VMEM((hq, sq, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((hq, sq, _STAT_LANES), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(kv_lens, jnp.int32),
       q, k_pages, v_pages)

@@ -2,9 +2,10 @@
 
 Each wrapper: picks a block plan (static: tuning.py — the acc chunk
 model; measured: an ``autotune.KernelTuner`` passed as ``tuner=``), pads
-to the plan, dispatches the kernel, unpads.  ``interpret`` defaults to
-True off-TPU so the same call sites validate on CPU and run
-Mosaic-compiled on TPU.
+to the plan, dispatches the kernel, unpads.  ``interpret=None`` passes
+through to the kernel, which resolves it (``tuning.resolve_interpret``:
+the interpreter off-TPU, Mosaic-compiled on TPU), so the same call sites
+validate on CPU.
 
 The ``tuner=`` path is the paper's feedback loop at the kernel grid:
 the tuner wall-clocks candidate blocks seeded from the analytic prior on
@@ -27,10 +28,6 @@ from .artificial_work import artificial_work_pallas
 from .flash_attention import flash_attention_pallas
 from .reduce_scan import inclusive_scan_pallas, reduce_sum_pallas
 from .rmsnorm import rmsnorm_pallas
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_1d(x: jax.Array, padded: int, fill=0.0):
@@ -65,7 +62,6 @@ def adjacent_difference(x: jax.Array, *, block: int | None = None,
                         interpret: bool | None = None,
                         tuner=None) -> jax.Array:
     n = x.shape[0]
-    interpret = _default_interpret() if interpret is None else interpret
     if block is None and tuner is not None:
         block = _tuned_block_1d(
             tuner, "adjacent_difference", n, x.dtype, arrays_in_vmem=3,
@@ -89,7 +85,6 @@ def artificial_work(x: jax.Array, *, iters: int = 256,
                     interpret: bool | None = None,
                     tuner=None) -> jax.Array:
     n = x.shape[0]
-    interpret = _default_interpret() if interpret is None else interpret
     if block is None and tuner is not None:
         block = _tuned_block_1d(
             tuner, f"artificial_work_{iters}", n, x.dtype, arrays_in_vmem=2,
@@ -109,7 +104,6 @@ def _rsum_call(x, block, interpret):
 def reduce_sum(x: jax.Array, *, block: int | None = None,
                interpret: bool | None = None, tuner=None) -> jax.Array:
     n = x.shape[0]
-    interpret = _default_interpret() if interpret is None else interpret
     if block is None and tuner is not None:
         block = _tuned_block_1d(
             tuner, "reduce_sum", n, x.dtype, arrays_in_vmem=1,
@@ -129,7 +123,6 @@ def _iscan_call(x, block, interpret):
 def inclusive_scan(x: jax.Array, *, block: int | None = None,
                    interpret: bool | None = None, tuner=None) -> jax.Array:
     n = x.shape[0]
-    interpret = _default_interpret() if interpret is None else interpret
     if block is None and tuner is not None:
         block = _tuned_block_1d(
             tuner, "inclusive_scan", n, x.dtype, arrays_in_vmem=2,
@@ -189,7 +182,6 @@ def rmsnorm(x: jax.Array, gamma: jax.Array, *, eps: float = 1e-6,
     for s in shape[:-1]:
         rows *= s
     x2 = x.reshape(rows, d)
-    interpret = _default_interpret() if interpret is None else interpret
     if block_rows is None and tuner is not None:
         # Row blocks: an element is one d-wide row, tiles are sublanes.
         def run(br: int) -> None:
@@ -222,7 +214,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
     skv = k.shape[2]
-    interpret = _default_interpret() if interpret is None else interpret
     # The tuner searches (block_q, block_kv) *pairs*; with one block
     # pinned by the caller the winner's other half would come from a
     # pairing that was never measured, so the search only runs when both

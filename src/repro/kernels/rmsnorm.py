@@ -8,6 +8,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tuning import resolve_interpret
+
 
 def _kernel(x_ref, g_ref, o_ref, *, eps: float):
     xf = x_ref[...].astype(jnp.float32)
@@ -18,7 +20,7 @@ def _kernel(x_ref, g_ref, o_ref, *, eps: float):
 
 def rmsnorm_pallas(x: jax.Array, gamma: jax.Array, *, eps: float = 1e-6,
                    block_rows: int = 128, plan=None,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """x: (rows, d); gamma: (d,).  rows must divide by block_rows
     (ops.py pads).  An externally-chosen ``plan`` (a ``tuning.BlockPlan``,
     e.g. a measured winner from ``autotune.KernelTuner``) overrides
@@ -34,5 +36,5 @@ def rmsnorm_pallas(x: jax.Array, gamma: jax.Array, *, eps: float = 1e-6,
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, gamma)

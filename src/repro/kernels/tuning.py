@@ -24,10 +24,27 @@ from ..core.overhead_law import DEFAULT_CHUNKS_PER_CORE
 
 LANE = 128          # TPU lane width (last dim tile)
 SUBLANE = 8         # float32 sublane tile (second-to-last dim)
+# A 1-d f32 array is laid out in whole (SUBLANE, LANE) tiles, and Mosaic
+# refuses a 1-d block that does not divide that tiling ("has tiling
+# (1024), but its block shape ... is not divisible"): 1-d blocks are
+# multiples of this.
+TILE_1D = LANE * SUBLANE
 
 
 def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret=None`` means: compile with Mosaic on a TPU backend,
+    run the Pallas interpreter anywhere else.  Every kernel entry point
+    resolves its argument here, so a caller on the chip never lands in
+    the interpreter by default."""
+    if interpret is None:
+        import jax
+
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,7 +55,7 @@ class BlockPlan:
 
 
 def max_block_1d(*, bytes_per_elem: int, arrays_in_vmem: int,
-                 hw: HardwareSpec = TPU_V5E, align: int = LANE,
+                 hw: HardwareSpec = TPU_V5E, align: int = TILE_1D,
                  vmem_fraction: float = 0.25) -> int:
     """Largest legal 1-d block under the double-buffered VMEM budget,
     floored at one tile.  The single budget model shared by the analytic
@@ -61,25 +78,23 @@ def plan_1d(n: int, *, bytes_per_elem: int = 4,
             arrays_in_vmem: int = 2,
             hw: HardwareSpec = TPU_V5E,
             chunks_per_core: int = DEFAULT_CHUNKS_PER_CORE,
-            vmem_fraction: float = 0.25) -> BlockPlan:
+            vmem_fraction: float = 0.25,
+            align: int = TILE_1D) -> BlockPlan:
     """Choose a 1-d block size for an elementwise/stencil kernel.
 
     Eq. 10 with N_C = 1 TensorCore: block = N / C, then clamped to
-    [LANE*SUBLANE, vmem_budget] and rounded to the hardware tile.
+    [align, vmem_budget] and rounded to the hardware tile (``align``).
     ``arrays_in_vmem`` counts live blocks (in + out + halo...) so double
-    buffering fits: budget = vmem * fraction / (2 * arrays).
+    buffering fits: budget = vmem * fraction / (2 * arrays).  The VMEM
+    budget is the hard constraint: ``max_block_1d`` floors it at one
+    tile, never above.
     """
     n = max(int(n), 1)
     max_block = max_block_1d(bytes_per_elem=bytes_per_elem,
                              arrays_in_vmem=arrays_in_vmem, hw=hw,
-                             vmem_fraction=vmem_fraction)
-    # A small budget can push max_block below the preferred minimum; the
-    # VMEM budget is the hard constraint, so the minimum shrinks (down to
-    # one LANE tile) rather than the block exceeding the budget.
-    min_block = min(LANE * SUBLANE, max_block)
-    target = round_up(math.ceil(n / chunks_per_core), LANE)
-    block = max(min(target, max_block), min_block)
-    block = min(block, round_up(n, LANE), max_block)
+                             vmem_fraction=vmem_fraction, align=align)
+    target = round_up(math.ceil(n / chunks_per_core), align)
+    block = min(max(target, align), max_block, round_up(n, align))
     grid = math.ceil(n / block)
     return BlockPlan(block=block, grid=grid, padded=block * grid)
 

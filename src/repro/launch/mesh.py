@@ -13,17 +13,10 @@ import numpy as np
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    ``jax.sharding.AxisType`` (and make_mesh's ``axis_types=``) only
-    exist from jax 0.5; 0.4.x builds the mesh without them — every axis
-    is Auto there anyway, which is exactly what we'd request.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto``: GSPMD propagates the
+    shardings this repo places, nothing is explicit-sharded."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -34,13 +34,14 @@ from ..data import make_batch
 from ..models import lm
 from ..serve import (ServeEngine, ServeFrontend, ServeScheduler, SLOModel,
                      heavy_tailed_trace, materialize, percentile)
+from .compile_cache import place_compile_cache
 
 # Recommended process environment for serving runs — (var, value, why).
 # Source it with:  eval "$(python -m repro.launch.serve --print-launch-profile)"
 # The malloc and logging lines follow the launch scripts of production
-# JAX training rigs (SNIPPETS §1-2); the compilation-cache lines keep
-# warm-start latency flat across process restarts, which matters for a
-# serving tier that redeploys often.
+# JAX training rigs (SNIPPETS §1-2).  The compilation cache itself is
+# placed by launch/compile_cache (JAX_COMPILATION_CACHE_DIR if set, else
+# <repo>/.jax_cache); the threshold line decides what goes into it.
 LAUNCH_PROFILE = (
     ("LD_PRELOAD", "/usr/lib/x86_64-linux-gnu/libtcmalloc.so.4",
      "tcmalloc: faster malloc under slot-pool churn (skip if absent)"),
@@ -48,10 +49,6 @@ LAUNCH_PROFILE = (
      "silence large-alloc warnings for cache-pool buffers"),
     ("TF_CPP_MIN_LOG_LEVEL", "4",
      "quiet XLA/TSL startup chatter on the serving console"),
-    ("XLA_FLAGS", "--xla_cpu_multi_thread_eigen=true",
-     "threaded CPU backend for host-fallback ops"),
-    ("JAX_COMPILATION_CACHE_DIR", "~/.cache/repro-jax-cache",
-     "persist compiled executables across restarts"),
     ("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1",
      "cache anything that took >=1s to compile"),
 )
@@ -220,6 +217,7 @@ def main() -> None:
         return
     if args.arch is None:
         ap.error("--arch is required (unless --print-launch-profile)")
+    place_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

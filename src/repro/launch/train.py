@@ -26,6 +26,7 @@ from ..optim import AdamWConfig, adamw
 from ..runtime import FaultTolerantTrainer
 from ..train import make_train_step
 from . import mesh as mesh_lib
+from .compile_cache import place_compile_cache
 
 
 def main() -> None:
@@ -63,6 +64,7 @@ def main() -> None:
 
     if args.strict:
         strict.enable()
+    place_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -1268,46 +1268,36 @@ class ServeScheduler:
                 "tokens_per_verify": tpv,
                 "acceptance_rate": max(accept, 0.0)}
 
-    def decode_cost_analysis(self) -> dict | None:
+    def decode_cost_analysis(self) -> dict:
         """Per-device XLA costs of one decode loop iteration: flops,
         HBM bytes accessed, and collective wire bytes (analysis/roofline
         conventions; ``cost_analysis()`` is per-device, and a
         ``fori_loop`` body is counted once — i.e. the numbers are per
         iteration, so achieved TFLOP/s = flops × ``decode_loop_iters`` /
         makespan).  Lowering reuses the already-compiled executable via
-        the jit cache; None when nothing has compiled cleanly."""
+        the jit cache; a step that fails to lower or compile raises."""
         from ..analysis import roofline
 
         n = self.pool.n_slots
         toks = jnp.zeros(n, jnp.int32)
         poss = self.pool.positions_array()
-        try:
-            if self._fused and self._spec and self._spec_depth >= 2:
-                # Speculative hot path: cost the spec step's loop body
-                # (one verify round — ``decode_loop_iters`` counts
-                # exactly those rounds on this path).
-                pt = (self.pool.page_table_array(),) if self.paged else ()
-                hist = jnp.full((n, self.spec_history), -1, jnp.int32)
-                lowered = self._spec_step(self._spec_depth).lower(
-                    self.params, self.pool.caches, *pt, hist, toks,
-                    poss, jnp.zeros(n, jnp.int32))
-            elif self._fused and self.paged:
-                lowered = self._fused_step().lower(
-                    self.params, self.pool.caches,
-                    self.pool.page_table_array(), toks, poss,
-                    jnp.zeros(n, jnp.int32))
-            elif self._fused:
-                lowered = self._fused_step().lower(
-                    self.params, self.pool.caches, toks, poss,
-                    jnp.zeros(n, jnp.int32))
-            else:
-                lowered = self._decode_step().lower(
-                    self.params, self.pool.caches, toks, poss,
-                    jnp.zeros(n, dtype=bool))
-            flops, byts, wire, _ = roofline.extract_costs(
-                lowered.compile())
-        except Exception:       # pragma: no cover - backend-dependent
-            return None
+        steps = jnp.zeros(n, jnp.int32)
+        pt = (self.pool.page_table_array(),) if self.paged else ()
+        if self._fused and self._spec and self._spec_depth >= 2:
+            # Speculative hot path: cost the spec step's loop body (one
+            # verify round — ``decode_loop_iters`` counts exactly those
+            # rounds on this path).
+            hist = jnp.full((n, self.spec_history), -1, jnp.int32)
+            lowered = self._spec_step(self._spec_depth).lower(
+                self.params, self.pool.caches, *pt, hist, toks, poss, steps)
+        elif self._fused:
+            lowered = self._fused_step().lower(
+                self.params, self.pool.caches, *pt, toks, poss, steps)
+        else:
+            lowered = self._decode_step().lower(
+                self.params, self.pool.caches, toks, poss,
+                jnp.zeros(n, dtype=bool))
+        flops, byts, wire, _ = roofline.extract_costs(lowered.compile())
         return {"flops_per_device": flops,
                 "hbm_bytes_per_device": byts,
                 "collective_wire_bytes_per_device": wire,

@@ -17,23 +17,15 @@ error is bounded by 1/127 of the max summed gradient (documented).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..algorithms.detail import shard_map
 from ..configs.base import ArchConfig
 from ..optim import adamw
 from . import train_loop
-
-# The "don't verify replication" switch was renamed check_rep -> check_vma
-# when shard_map moved out of jax.experimental; pass whichever this jax
-# spells (the detail.shard_map alias already bridges the module move).
-_CHECK_KW = "check_vma" if "check_vma" in \
-    inspect.signature(shard_map).parameters else "check_rep"
 
 
 def _quantize(g: jax.Array, axis: str) -> tuple[jax.Array, jax.Array]:
@@ -101,11 +93,11 @@ def make_compressed_dp_train_step(cfg: ArchConfig,
         ef_new = jax.tree.map(lambda e: e[None], ef_new)  # re-add dev dim
         return new_params, new_state, ef_new, metrics
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis)),
         out_specs=(P(), P(), P(axis), P()),
-        **{_CHECK_KW: False}))
+        check_vma=False))
 
 
 def init_error_feedback(params, n_dev: int):

@@ -38,18 +38,19 @@ def test_candidates_1d_legal(n, bytes_per_elem, arrays_in_vmem):
     budget = TPU_V5E.vmem_bytes * 0.25 / (2.0 * arrays_in_vmem)
     assert cands, (n, bytes_per_elem)
     assert len(set(cands)) == len(cands)
+    tile = tuning.TILE_1D
     for b in cands:
-        assert b % tuning.LANE == 0, (n, b)
+        assert b % tile == 0, (n, b)
         assert b <= cap
         # either inside the budget, or the single smallest legal tile
-        assert b * bytes_per_elem <= budget or b == tuning.LANE
+        assert b * bytes_per_elem <= budget or b == tile
         # never wider than the padded problem
-        assert b <= ((n + tuning.LANE - 1) // tuning.LANE) * tuning.LANE
+        assert b <= tuning.round_up(n, tile)
     # the analytic prior leads the candidate list
     assert cands[0] == min(
         tuning.plan_1d(n, bytes_per_elem=bytes_per_elem,
                        arrays_in_vmem=arrays_in_vmem).block,
-        ((max(n, 1) + tuning.LANE - 1) // tuning.LANE) * tuning.LANE)
+        tuning.round_up(max(n, 1), tile))
 
 
 @pytest.mark.parametrize("align", [tuning.SUBLANE, tuning.LANE])
@@ -397,14 +398,16 @@ def test_timed_chunk_fn_skips_unknown_size():
 
 
 # ---------------------------------------------------------------------------
-# satellite: jax-0.4.37 mesh compat wrapper
+# launch/mesh.make_mesh on the installed jax
 # ---------------------------------------------------------------------------
 
 def test_make_mesh_compat_current_jax():
     from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh((len(jax.devices()),), ("data",))
-    assert mesh.shape["data"] == len(jax.devices())
+    n = len(jax.devices())
+    mesh = make_mesh((n, 1), ("data", "model"))
+    assert dict(mesh.shape) == {"data": n, "model": 1}
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 2
 
 
 # ---------------------------------------------------------------------------
