@@ -48,7 +48,6 @@ import asyncio
 import json
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -134,10 +133,11 @@ async def replay(frontend: ServeFrontend, mat_trace, *,
     for i, (tr, _) in enumerate(mat_trace):
         if rng.random_sample() < cancel_frac and tr.new_tokens >= 2:
             cancel_at[i] = int(rng.randint(1, tr.new_tokens))
-    t0 = time.monotonic()
+    clock = frontend.clock      # deadlines are on the scheduler's clock
+    t0 = clock()
 
     async def one(i, tr, prompt):
-        delay = tr.arrival_s - (time.monotonic() - t0)
+        delay = tr.arrival_s - (clock() - t0)
         if delay > 0:
             await asyncio.sleep(delay)
         deadline = None if tr.deadline_s is None else t0 + tr.deadline_s
@@ -155,7 +155,7 @@ async def replay(frontend: ServeFrontend, mat_trace, *,
 
     await asyncio.gather(*(one(i, tr, p)
                            for i, (tr, p) in enumerate(mat_trace)))
-    return time.monotonic() - t0
+    return clock() - t0
 
 
 def run_config(name: str, cfg, params, mat_trace, *, n_slots: int,

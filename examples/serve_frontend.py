@@ -5,7 +5,6 @@ backpressure, all over one scheduler with adaptive admission.
     PYTHONPATH=src python examples/serve_frontend.py
 """
 import asyncio
-import time
 
 import jax
 
@@ -61,7 +60,7 @@ async def main():
         # 3. A request whose deadline already passed: shed before its
         #    prefill burns a slot (enforce_deadlines is on by default).
         dead = await fe.submit(prompts[0][:8], 8,
-                               deadline=time.monotonic() - 1.0)
+                               deadline=fe.clock() - 1.0)
 
         async def doomed():
             async for _tok in dead:

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import collections
 import time
 
 import jax
@@ -33,7 +34,7 @@ from ..core.executor import SequentialExecutor
 from ..data import make_batch
 from ..models import lm
 from ..serve import (ServeEngine, ServeFrontend, ServeScheduler, SLOModel,
-                     heavy_tailed_trace, materialize, percentile)
+                     heavy_tailed_trace, materialize, percentile, spans)
 from .compile_cache import place_compile_cache
 
 # Recommended process environment for serving runs — (var, value, why).
@@ -78,6 +79,31 @@ def serve_cross_attention(cfg, params, args, executor, tuner=None) -> None:
     print("sample:", out[0].tolist())
 
 
+def print_span_summary(sched: ServeScheduler) -> None:
+    """What the program's own spans (serve/spans.py) saw this run: host
+    seconds by span name (nested spans count in each), queue wait
+    (admission less submission) over the requests ``serve.request``
+    recorded (it spans a request's life, not host work), and
+    the decode loop's lane-iterations by what each slot did."""
+    held = spans.RECORDER.held()
+    by_name: collections.Counter = collections.Counter()
+    for s in held:
+        if s.name != "serve.request":
+            by_name[s.name] += s.end - s.start
+    print("host seconds by span: " + ", ".join(
+        f"{name} {sec:.3f}" for name, sec in by_name.most_common()))
+    waits = [s.attrs["admitted"] - s.attrs["submitted"] for s in held
+             if s.name == "serve.request" and s.attrs["admitted"] is not None]
+    print(f"queue wait over {len(waits)} requests: "
+          f"p50={percentile(waits, 50) * 1e3:.1f}ms "
+          f"p95={percentile(waits, 95) * 1e3:.1f}ms")
+    lanes = sum(sched.lane_iters.values())
+    if lanes:
+        print("decode lanes: " + " ".join(
+            f"{kind} {100.0 * n / lanes:.1f}%"
+            for kind, n in sched.lane_iters.items()))
+
+
 def serve_frontend(sched: ServeScheduler, args) -> None:
     """Async front-end replay: a seeded heavy-tailed open-loop trace
     with streaming consumers and per-request SLO deadlines — the mode
@@ -92,10 +118,11 @@ def serve_frontend(sched: ServeScheduler, args) -> None:
 
     async def replay():
         async with frontend:
-            t0 = time.monotonic()
+            clock = frontend.clock   # deadlines: the scheduler's clock
+            t0 = clock()
 
             async def one(tr, prompt):
-                delay = tr.arrival_s - (time.monotonic() - t0)
+                delay = tr.arrival_s - (clock() - t0)
                 if delay > 0:
                     await asyncio.sleep(delay)
                 deadline = None if tr.deadline_s is None \
@@ -106,7 +133,7 @@ def serve_frontend(sched: ServeScheduler, args) -> None:
                     pass
 
             await asyncio.gather(*(one(tr, p) for tr, p in mat))
-            return time.monotonic() - t0
+            return clock() - t0
 
     makespan = asyncio.run(replay())
     stats = frontend.stats()
@@ -299,6 +326,7 @@ def main() -> None:
     if args.frontend:
         serve_frontend(sched, args)
         print_paged_stats()
+        print_span_summary(sched)
         if args.explain_decisions:
             model = sched.decision_model()
             if model is not None:
@@ -339,6 +367,7 @@ def main() -> None:
           f"ttft p50={percentile(ttfts, 50) * 1e3:.0f}ms")
     print("sample:", outs[rids[0]])
     print_paged_stats()
+    print_span_summary(sched)
     if tuner is not None:
         print(f"kernel autotune: {tuner.searches} measured searches, "
               f"{tuner.cache_hits} persisted winners reused")

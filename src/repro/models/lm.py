@@ -106,12 +106,14 @@ def init_params(key, cfg: ArchConfig) -> dict:
 def _apply_shared(shared: dict, proj: dict, x, cfg: ArchConfig, *,
                   positions, window, impl, cache=None, cache_pos=None):
     h = cm.rmsnorm(shared["ln1"], x, cfg.norm_eps)
-    a, new_cache = attention.self_attention(
-        shared["attn"], h, cfg, positions=positions, window=window,
-        impl=impl, cache=cache, cache_pos=cache_pos)
+    with jax.named_scope("attention"):
+        a, new_cache = attention.self_attention(
+            shared["attn"], h, cfg, positions=positions, window=window,
+            impl=impl, cache=cache, cache_pos=cache_pos)
     h = h + a
     h2 = cm.rmsnorm(shared["ln2"], h, cfg.norm_eps)
-    h = h + ffn.apply(shared["ffn"], h2, cfg)
+    with jax.named_scope("mlp"):
+        h = h + ffn.apply(shared["ffn"], h2, cfg)
     return x + cm.linear(proj, h, jnp.dtype(cfg.compute_dtype)), new_cache
 
 
@@ -124,16 +126,18 @@ def _apply_block(kind: str, p: dict, x, cfg: ArchConfig, *,
     new_cache = None
     if kind == "attn":
         h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        a, new_cache = attention.self_attention(
-            p["attn"], h, cfg, positions=positions, window=window,
-            impl=impl, cache=cache, cache_pos=cache_pos)
+        with jax.named_scope("attention"):
+            a, new_cache = attention.self_attention(
+                p["attn"], h, cfg, positions=positions, window=window,
+                impl=impl, cache=cache, cache_pos=cache_pos)
         x = x + a
         h = cm.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        if cfg.n_experts:
-            y, aux = moe.apply(p["moe"], h, cfg)
-            x = x + y
-        elif cfg.d_ff:
-            x = x + ffn.apply(p["ffn"], h, cfg)
+        with jax.named_scope("mlp"):
+            if cfg.n_experts:
+                y, aux = moe.apply(p["moe"], h, cfg)
+                x = x + y
+            elif cfg.d_ff:
+                x = x + ffn.apply(p["ffn"], h, cfg)
     elif kind == "cross_attn":
         h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
         feats = frontend_feats
@@ -165,6 +169,7 @@ def _apply_block(kind: str, p: dict, x, cfg: ArchConfig, *,
     return x, aux, new_cache
 
 
+@jax.named_scope("head")
 def _logits(params, cfg: ArchConfig, x):
     cd = jnp.dtype(cfg.compute_dtype)
     x = cm.rmsnorm(params["final_norm"], x, cfg.norm_eps)

@@ -1,3 +1,4 @@
+from . import spans
 from .decode_loop import (DEFAULT_MAX_DEPTH, DEFAULT_SPEC_HISTORY,
                           SPEC_DEPTH_CANDIDATES, draft_from_history,
                           make_fused_decode_step, make_lane_step,
@@ -30,4 +31,5 @@ __all__ = [
     "DEFAULT_SPEC_HISTORY", "SPEC_DEPTH_CANDIDATES",
     "draft_from_history", "make_spec_decode_step",
     "make_paged_spec_decode_step",
+    "spans",
 ]

@@ -81,11 +81,12 @@ def make_lane_step(cfg: ArchConfig, *, window: int | None = None,
     return jax.vmap(lane, in_axes=(None, 0, 0, 0))
 
 
+@jax.named_scope("merge")
 def masked_merge(caches, new_caches, active):
     """Keep ``new`` rows only for active lanes: inactive lanes (free
     slots, mid-prefill slots, lanes past their stop point) must not see
     their KV rows or recurrent states advanced by the garbage token
-    their lane computed."""
+    their lane computed.  Its operations carry the scope ``merge``."""
 
     def keep(old, new):
         if old is None:

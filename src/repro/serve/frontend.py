@@ -50,6 +50,7 @@ import asyncio
 import dataclasses
 from typing import Any
 
+from . import spans
 from .scheduler import (TERMINAL_STATES, PromptTooLongError,  # noqa: F401
                         RequestState, ServeScheduler)
 
@@ -252,8 +253,14 @@ class ServeFrontend:
 
     def _pump(self) -> None:
         """Move freshly landed tokens into each stream; close streams
-        whose requests went terminal."""
-        now = self.clock()
+        whose requests went terminal.  Each token keeps the time it
+        landed on the host (``Request.landed_at``): a fused dispatch's
+        tokens land together, when its drain fetched them."""
+        with spans.span("serve.pump") as sp:
+            sp.attrs["tokens"] = self._pump_streams()
+
+    def _pump_streams(self) -> int:
+        pumped = 0
         for rid in list(self._streams):
             req = self.sched.requests.get(rid)
             if req is None:     # cleared behind our back
@@ -263,31 +270,21 @@ class ServeFrontend:
             seen = self._emitted[rid]
             fresh = req.out[seen:]
             if fresh:
-                if rec.first_token_at is None:
-                    rec.first_token_at = req.first_token_at \
-                        if req.first_token_at is not None else now
-                stream = self._streams[rid]
-                # Inter-token timestamps: a fused dispatch drains k
-                # tokens in one burst, and stamping them all ``now``
-                # would report 0ms gaps (the itl_p99 the load harness
-                # aggregates).  The tokens were *produced* spread across
-                # the dispatch interval, so spread their emission times
-                # linearly from the request's previous stamp to now —
-                # the stream consumer still receives them in order, and
-                # the last token of a burst keeps the exact drain time.
-                prev = rec.token_times[-1] if rec.token_times \
-                    else rec.first_token_at
-                span = max(now - prev, 0.0)
                 k = len(fresh)
-                for i, tok in enumerate(fresh, start=1):
-                    rec.token_times.append(prev + span * i / k)
+                if rec.first_token_at is None:
+                    rec.first_token_at = req.first_token_at
+                rec.token_times.extend(req.landed_at[seen:seen + k])
+                stream = self._streams[rid]
+                for tok in fresh:
                     stream._q.put_nowait(tok)
                 rec.tokens += k
                 self._emitted[rid] = seen + k
+                pumped += k
             if req.state in TERMINAL_STATES and (
                     req.state is not RequestState.DONE
                     or req.pending_out <= 0):
                 self._close(rid, req.state.value, req)
+        return pumped
 
     def _close(self, rid: int, status: str, req=None) -> None:
         stream = self._streams.pop(rid, None)

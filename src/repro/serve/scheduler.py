@@ -73,6 +73,7 @@ from ..core.model import (DEFAULT_SPEC_ACCEPT, DecisionKey, ExecutionModel,
 from ..core.properties import params_of
 from ..models import flags, lm
 from ..train.autotune import serve_profiles
+from . import spans
 from .decode_loop import (DEFAULT_MAX_DEPTH, DEFAULT_SPEC_HISTORY,
                           SPEC_DEPTH_CANDIDATES, _check_spec_arch,
                           make_fused_decode_step, make_lane_step,
@@ -91,6 +92,17 @@ DEFAULT_CHUNK_BUCKETS = (8, 16, 32, 64, 128, 256)
 # refreshes the acceptance EMA at a bounded tax (one wider verify per
 # SPEC_PROBE_EVERY windows) and is byte-identical like any spec step.
 SPEC_PROBE_EVERY = 16
+
+# What a slot did in one iteration of the fused decode loop, counted at
+# each dispatch (``ServeScheduler.lane_iters``; the ``serve.dispatch``
+# span carries the same counts as ``lane_<kind>`` attrs):
+#   decode      decoding and producing a token (sums to ``take``)
+#   clamped     decoding, but its budget ran out before the loop's end
+#   prefill     holding a request still in prefill
+#   free_queued free while requests wait after this tick's admission
+#   free_idle   free with nothing waiting
+LANES = ("decode", "clamped", "prefill", "free_queued", "free_idle")
+_LANE_ATTRS = tuple(f"lane_{kind}" for kind in LANES)
 
 
 class PromptTooLongError(ValueError):
@@ -147,8 +159,12 @@ class Request:
     # (fused decode path): the scheduling budget counts them, the
     # emitted output gains them only when their buffer is harvested.
     pending_out: int = 0
+    admitted_at: float | None = None
     first_token_at: float | None = None
     finished_at: float | None = None
+    # The scheduler-clock time each token of ``out`` landed on the host:
+    # the prefill's first-token fetch, then each fused dispatch's drain.
+    landed_at: list[float] = dataclasses.field(default_factory=list)
     # Host-side prompt tokens, captured at submit() time (outside the
     # strict-mode transfer guard) — the paged pool's prefix-cache key.
     host_tokens: tuple | None = None
@@ -197,7 +213,7 @@ class ServeScheduler:
                  max_len: int, window: int | None = None,
                  executor=None, acc: AdaptiveCoreChunk | None = None,
                  chunk_buckets: Sequence[int] = DEFAULT_CHUNK_BUCKETS,
-                 clock: Callable[[], float] = time.monotonic,
+                 clock: Callable[[], float] = time.perf_counter,
                  kernel_tuner=None,
                  dispatch_depth: int | str | None = None,
                  max_dispatch_depth: int = DEFAULT_MAX_DEPTH,
@@ -255,6 +271,8 @@ class ServeScheduler:
                 cfg, mesh, params, n_slots, max_len)
             self.params = jax.device_put(params, pshard)
         self.slots_per_replica = n_slots // self.n_replicas
+        # Request stamps (arrival, admission, tokens, finish).  The
+        # default is the clock of serve/spans.py and the profiler trace.
         self.clock = clock
         self.chunk_buckets = tuple(sorted(set(int(b) for b in chunk_buckets
                                               if b > 0))) or (max_len,)
@@ -448,6 +466,9 @@ class ServeScheduler:
         # the multiplier for decode_cost_analysis()'s per-iteration
         # flops/bytes in the benchmark's TFLOP/s + HBM-BW accounting.
         self.decode_loop_iters = 0
+        # Fused decode: every loop iteration counts each slot once under
+        # one of ``LANES`` (the five sum to n_slots per iteration).
+        self.lane_iters = dict.fromkeys(LANES, 0)
         self.host_roundtrips = 0    # block/device_get events, all paths
         self.host_overhead_s = 0.0  # tick wall-clock minus device waits
         self._blocked_s = 0.0
@@ -529,6 +550,7 @@ class ServeScheduler:
                 req.slot = None
         req.state = RequestState.CANCELLED
         req.finished_at = self.clock()
+        self._record_request(req)
         self.cancelled += 1
         return True
 
@@ -647,7 +669,10 @@ class ServeScheduler:
 
         The wall-clock of everything that is *not* a device wait is
         accumulated as ``host_overhead_s`` — the per-dispatch T0 the
-        fused path amortises.  On fused decode-only ticks it is also
+        fused path amortises: the ``serve.tick`` span less the waits
+        inside it (``_blocked_s``: the stretch of each prefill's chunk
+        thunks, drains that blocked, synced launches).  On fused
+        decode-only ticks it is also
         folded into the calibration store (``serve_host_tick``), which
         is what drives the next ``serve_dispatch_depth`` decision.
 
@@ -658,13 +683,13 @@ class ServeScheduler:
         bug the guard turns into a hard error.
         """
         with strict.hot_dispatch_guard():
-            t_start = time.perf_counter()
             self._blocked_s = 0.0
             self._tick_misses = 0
             was_warm = self._warm_fused
-            rec = self._tick_fused() if self._fused else self._tick_legacy()
-            host_s = max(
-                time.perf_counter() - t_start - self._blocked_s, 0.0)
+            with spans.span("serve.tick", tick=self._tick) as sp:
+                rec = self._tick_fused() if self._fused \
+                    else self._tick_legacy()
+            host_s = max(sp.end - sp.start - self._blocked_s, 0.0)
             self.host_overhead_s += host_s
             if self._fused and was_warm and rec.decoded \
                     and not rec.prefill_ops:
@@ -759,6 +784,13 @@ class ServeScheduler:
         use: they are shed before prefill and counted as misses).  With
         ``admission="adaptive"`` the number of slots filled this tick is
         a ``serve_admission`` engine decision, not "all of them"."""
+        with spans.span("serve.admit") as sp:
+            admitted = self._admit_waiting()
+            sp.attrs["admitted"] = len(admitted)
+            sp.attrs["queue"] = self._queue_depth
+        return admitted
+
+    def _admit_waiting(self) -> list[int]:
         if self.shed_expired and self._waiting:
             now = self.clock()
             kept = []
@@ -766,6 +798,7 @@ class ServeScheduler:
                 if req.deadline is not None and now > req.deadline:
                     req.state = RequestState.SHED
                     req.finished_at = now
+                    self._record_request(req)
                     self.shed += 1
                     self.deadline_misses += 1
                     self._tick_misses += 1
@@ -778,6 +811,7 @@ class ServeScheduler:
         width = self._decide_admission()
         lane_cap = self._decide_mesh_batch()
         admitted = []
+        now = None
         while self._waiting and self.pool.free_slots() \
                 and (width is None or len(admitted) < width) \
                 and (lane_cap is None or len(self._active) < lane_cap):
@@ -790,6 +824,9 @@ class ServeScheduler:
                 req.prefilled = reused
             else:
                 req.slot = self.pool.acquire(req.rid)
+            if now is None:
+                now = self.clock()
+            req.admitted_at = now
             req.state = RequestState.PREFILL
             self._active.append(req)
             admitted.append(req.rid)
@@ -817,30 +854,31 @@ class ServeScheduler:
         model = self.decision_model()
         if model is None:       # static params object: no store, greedy
             return None
-        host = model.smoothed_t_iter(self.host_tick_key)
-        inputs: tuple = ()
-        if host is None:
-            # Same seed as the depth decision: the calibrated
-            # empty-dispatch T0 plus a few engine queries — the host
-            # work a tick provably pays before any tick was timed.
-            host = self.acc.calibrate_t0(self.executor) \
-                + 4.0 * decision_overhead_s()
-            inputs = (("seeded", True),)
-        head = self._waiting[0]
-        t_pf = self.acc.measure_iteration(
-            self.executor, self.prefill_profile,
-            max(head.remaining_prefill, 1), key=self.prefill_key)
-        req_cost = t_pf * max(head.remaining_prefill, 1)
-        slack = None if head.deadline is None \
-            else head.deadline - self.clock()
-        decision = model.admission_width(
-            self.admit_key, queue_depth=len(self._waiting),
-            free_slots=free, host_tick_s=host, request_cost_s=req_cost,
-            slack_s=slack, max_width=self.pool.n_slots,
-            eff=getattr(self.acc, "efficiency",
-                        overhead_law.DEFAULT_EFFICIENCY),
-            evidence=(self.host_tick_key, self.prefill_key),
-            inputs=inputs)
+        with spans.span("serve.decide", kind="admission"):
+            host = model.smoothed_t_iter(self.host_tick_key)
+            inputs: tuple = ()
+            if host is None:
+                # Same seed as the depth decision: the calibrated
+                # empty-dispatch T0 plus a few engine queries — the host
+                # work a tick provably pays before any tick was timed.
+                host = self.acc.calibrate_t0(self.executor) \
+                    + 4.0 * decision_overhead_s()
+                inputs = (("seeded", True),)
+            head = self._waiting[0]
+            t_pf = self.acc.measure_iteration(
+                self.executor, self.prefill_profile,
+                max(head.remaining_prefill, 1), key=self.prefill_key)
+            req_cost = t_pf * max(head.remaining_prefill, 1)
+            slack = None if head.deadline is None \
+                else head.deadline - self.clock()
+            decision = model.admission_width(
+                self.admit_key, queue_depth=len(self._waiting),
+                free_slots=free, host_tick_s=host, request_cost_s=req_cost,
+                slack_s=slack, max_width=self.pool.n_slots,
+                eff=getattr(self.acc, "efficiency",
+                            overhead_law.DEFAULT_EFFICIENCY),
+                evidence=(self.host_tick_key, self.prefill_key),
+                inputs=inputs)
         return decision.cores
 
     def _decide_mesh_batch(self) -> int | None:
@@ -861,27 +899,28 @@ class ServeScheduler:
         model = self.decision_model()
         if model is None:       # static params object: every slot
             return None
-        demand = len(self._waiting) + len(self._active)
-        evidence = [self.host_tick_key, self.fused_key]
-        inputs: tuple = (("mesh", self.mesh_desc),)
-        host = model.smoothed_t_iter(self.host_tick_key)
-        if host is None:
-            host = self.acc.calibrate_t0(self.executor) \
-                + 4.0 * decision_overhead_s()
-            inputs += (("seeded", True),)
-        dev = model.smoothed_t_iter(self.fused_key)
-        if dev is None:
-            dev = self.acc.measure_iteration(
-                self.executor, self.decode_profile, max(demand, 1),
-                key=self.decode_key)
-            evidence.append(self.decode_key)
-        decision = model.mesh_batch(
-            self.mesh_key, demand=demand, n_replicas=self.n_replicas,
-            slots_per_replica=self.slots_per_replica,
-            host_tick_s=host, device_step_s=dev,
-            eff=getattr(self.acc, "efficiency",
-                        overhead_law.DEFAULT_EFFICIENCY),
-            evidence=tuple(evidence), inputs=inputs)
+        with spans.span("serve.decide", kind="mesh_batch"):
+            demand = len(self._waiting) + len(self._active)
+            evidence = [self.host_tick_key, self.fused_key]
+            inputs: tuple = (("mesh", self.mesh_desc),)
+            host = model.smoothed_t_iter(self.host_tick_key)
+            if host is None:
+                host = self.acc.calibrate_t0(self.executor) \
+                    + 4.0 * decision_overhead_s()
+                inputs += (("seeded", True),)
+            dev = model.smoothed_t_iter(self.fused_key)
+            if dev is None:
+                dev = self.acc.measure_iteration(
+                    self.executor, self.decode_profile, max(demand, 1),
+                    key=self.decode_key)
+                evidence.append(self.decode_key)
+            decision = model.mesh_batch(
+                self.mesh_key, demand=demand, n_replicas=self.n_replicas,
+                slots_per_replica=self.slots_per_replica,
+                host_tick_s=host, device_step_s=dev,
+                eff=getattr(self.acc, "efficiency",
+                            overhead_law.DEFAULT_EFFICIENCY),
+                evidence=tuple(evidence), inputs=inputs)
         return decision.batch_width
 
     def _decide(self) -> tuple[int, int, int]:
@@ -902,27 +941,28 @@ class ServeScheduler:
         queued = pf_tokens + dec_tokens
         if queued <= 0:
             return 0, 0, 0
-        t_pf = self.acc.measure_iteration(
-            self.executor, self.prefill_profile, max(pf_tokens, 1),
-            key=self.prefill_key)
-        t_dec = self.acc.measure_iteration(
-            self.executor, self.decode_profile, max(dec_tokens, 1),
-            key=self.decode_key)
-        t_iter = (pf_tokens * t_pf + dec_tokens * t_dec) / queued
-        if hasattr(self.acc, "decide"):
-            # One engine query per tick: cores + chunk in a single traced
-            # decision (equivalent to the two customization-point calls
-            # below — decide() is what both of them derive from).
-            d = self.acc.decide(self.executor, t_iter, queued,
-                                key=self.tick_key,
-                                evidence=(self.prefill_key,
-                                          self.decode_key))
-            cores, chunk = d.n_cores, d.chunk_elems
-        else:
-            cores = self.acc.processing_units_count(self.executor, t_iter,
-                                                    queued)
-            chunk = self.acc.get_chunk_size(self.executor, t_iter, cores,
-                                            queued)
+        with spans.span("serve.decide", kind="tick"):
+            t_pf = self.acc.measure_iteration(
+                self.executor, self.prefill_profile, max(pf_tokens, 1),
+                key=self.prefill_key)
+            t_dec = self.acc.measure_iteration(
+                self.executor, self.decode_profile, max(dec_tokens, 1),
+                key=self.decode_key)
+            t_iter = (pf_tokens * t_pf + dec_tokens * t_dec) / queued
+            if hasattr(self.acc, "decide"):
+                # One engine query per tick: cores + chunk in a single traced
+                # decision (equivalent to the two customization-point calls
+                # below — decide() is what both of them derive from).
+                d = self.acc.decide(self.executor, t_iter, queued,
+                                    key=self.tick_key,
+                                    evidence=(self.prefill_key,
+                                              self.decode_key))
+                cores, chunk = d.n_cores, d.chunk_elems
+            else:
+                cores = self.acc.processing_units_count(self.executor, t_iter,
+                                                        queued)
+                chunk = self.acc.get_chunk_size(self.executor, t_iter, cores,
+                                                queued)
         return queued, max(cores, 1), max(chunk, 1)
 
     # -- prefill -------------------------------------------------------------
@@ -989,7 +1029,20 @@ class ServeScheduler:
                 model.observe(self.page_mgmt_key, len(ops),
                               max(time.perf_counter() - t_pg, 0.0))
 
+        n_dec = sum(1 for r in self._active
+                    if r.state is RequestState.DECODE)
+        with spans.span("serve.prefill", chunks=len(ops),
+                        tokens=sum(step for _, step, _ in ops),
+                        decoding=n_dec, inflight=len(self._inflight)) as sp:
+            return self._prefill_ops(ops, sp.id)
+
+    def _prefill_ops(self, ops: list, span_id: int):
+        """Run the chunks ``ops`` (request, step, padded width) through
+        the executor, write them back and route finished prompts into
+        decode.  ``span_id``: the ``serve.prefill`` span, the parent of
+        the chunk thunks' spans wherever the executor runs them."""
         pool, params = self.pool, self.params
+        waits: list = []    # (start, end) of each chunk thunk
 
         def run_chunk(chunk: Chunk):
             req, step, padded = ops[chunk.start]
@@ -997,24 +1050,31 @@ class ServeScheduler:
                 req.tokens, req.prefilled, step)
             if padded > step:
                 piece = jnp.pad(piece, (0, padded - step))
-            row = pool.read_slot(req.slot)
+            with spans.span("serve.prefill.read_slot",
+                            parent=span_id) as read:
+                row = pool.read_slot(req.slot)
             # Synchronise inside the thunk: the executor times this call
             # for the feedback loop, and an async jit dispatch would
             # record microseconds of launch cost as the chunk's t_iter.
-            return jax.block_until_ready(  # repro-lint: disable=RL002
-                self._prefill_step(padded)(
-                    params, row, piece[None], jnp.int32(req.prefilled),
-                    jnp.int32(step - 1)))
+            with spans.span("serve.prefill.chunk", parent=span_id,
+                            tokens=padded) as run:
+                out = jax.block_until_ready(  # repro-lint: disable=RL002
+                    self._prefill_step(padded)(
+                        params, row, piece[None], jnp.int32(req.prefilled),
+                        jnp.int32(step - 1)))
+            waits.append((read.start, run.end))
+            return out
 
         # Feedback only sees warm shapes: a tick whose ops include a
         # never-executed chunk width runs untimed (it compiles).
         if all(padded in self._warm_prefill for _, _, padded in ops):
             tag_workload(run_chunk, self.prefill_key)
-        t_dev = time.perf_counter()
         futs = self.executor.bulk_async_execute(
             run_chunk, [Chunk(i, step) for i, (_, step, _) in enumerate(ops)])
         outs = when_all(futs).result()
-        self._blocked_s += time.perf_counter() - t_dev
+        # The device wait: from the first thunk's slot read to the last
+        # chunk's end (thunks may run concurrently on a parallel executor).
+        self._blocked_s += max(b for _, b in waits) - min(a for a, _ in waits)
         self.host_roundtrips += 1
         self._warm_prefill.update(padded for _, _, padded in ops)
 
@@ -1023,14 +1083,15 @@ class ServeScheduler:
         prefill_ops, finished = [], []
         for (req, step, padded), (logits, new_row) in zip(ops, outs,
                                                           strict=True):
-            if self.paged:
-                # Scatter only the freshly-computed range into the
-                # slot's pages: rows before ``prefilled`` may belong to
-                # a shared (read-only) prefix.
-                self.pool.write_slot(req.slot, new_row, req.prefilled,
-                                     req.prefilled + padded)
-            else:
-                self.pool.write_slot(req.slot, new_row)
+            with spans.span("serve.prefill.write_slot"):
+                if self.paged:
+                    # Scatter only the freshly-computed range into the
+                    # slot's pages: rows before ``prefilled`` may belong
+                    # to a shared (read-only) prefix.
+                    self.pool.write_slot(req.slot, new_row, req.prefilled,
+                                         req.prefilled + padded)
+                else:
+                    self.pool.write_slot(req.slot, new_row)
             req.prefilled += step
             self.pool.positions[req.slot] = req.prefilled
             prefill_ops.append((req.rid, step))
@@ -1038,10 +1099,12 @@ class ServeScheduler:
                 # First-token sync: the scheduler needs this token on the
                 # host to route the request into decode.  Explicit so the
                 # strict-mode transfer guard stays armed for the rest.
-                tok = int(jax.device_get(  # repro-lint: disable=RL002
-                    jnp.argmax(logits[0, 0])))
+                with spans.span("serve.prefill.first_token"):
+                    tok = int(jax.device_get(  # repro-lint: disable=RL002
+                        jnp.argmax(logits[0, 0])))
                 req.out.append(tok)
                 req.first_token_at = self.clock()
+                req.landed_at.append(req.first_token_at)
                 req.state = RequestState.DECODE
                 if self.paged and req.host_tokens is not None:
                     # Publish the freshly-prefilled prompt's pages into
@@ -1110,6 +1173,7 @@ class ServeScheduler:
         self.pool.caches = new_caches
         next_toks = jax.device_get(next_toks)
         self._blocked_s += time.perf_counter() - t_dev
+        landed = self.clock()
         self.decode_dispatches += 1
         self.decode_tokens += len(decs)
         self.decode_loop_iters += 1
@@ -1119,6 +1183,7 @@ class ServeScheduler:
         for r in decs:
             self.pool.positions[r.slot] += 1
             r.out.append(int(next_toks[r.slot]))
+            r.landed_at.append(landed)
             decoded.append(r.rid)
             if len(r.out) >= r.max_new_tokens \
                     or self.pool.positions[r.slot] >= self.max_len:
@@ -1211,40 +1276,41 @@ class ServeScheduler:
             return d
         if model is None:     # static params object: no store to consult
             return min(2, cap)
-        evidence = (self.spec_accept_key, self.spec_step_key,
-                    self.fused_key)
-        inputs: tuple = ()
-        ema = model.smoothed_t_iter(self.spec_accept_key)
-        if ema is None:
-            accept = DEFAULT_SPEC_ACCEPT
-            inputs += (("seeded", True),)
-        else:
-            # The EMA already holds the acceptance rate (recovered at
-            # each dispatch's own width in ``_drain``) — no inversion.
-            accept = min(max(float(ema), 0.0), 0.999)
-            inputs += (("accept_ema", round(float(ema), 4)),)
-        step = model.smoothed_t_iter(self.spec_step_key) \
-            or model.smoothed_t_iter(self.fused_key) or 0.0
-        cands = tuple(c for c in SPEC_DEPTH_CANDIDATES if c <= cap) \
-            or (1,)
-        # Width cost measured, not assumed: the EMA'd speculative round
-        # seconds over the EMA'd width-1 iteration seconds prices the
-        # wider verify on *this* host and config (on CPU a width-2 GEMM
-        # can even beat the width-1 GEMV — the static prior cannot know
-        # that).  Falls back to the analytic prior until both step EMAs
-        # hold samples.
-        kwargs: dict = {}
-        spec_s = model.smoothed_t_iter(self.spec_step_key)
-        iter_s = model.smoothed_t_iter(self.fused_iter_key)
-        if spec_s and iter_s:
-            d_ref = max(self._spec_depth, 2)
-            wc = (spec_s / iter_s - 1.0) / (d_ref - 1.0)
-            kwargs["width_cost"] = min(max(wc, 0.0), 1.0)
-            inputs += (("width_cost_online", True),)
-        decision = model.spec_depth(
-            self.spec_depth_key, candidates=cands, accept_rate=accept,
-            step_s=step, max_depth=cap, current=self._spec_depth,
-            evidence=evidence, inputs=inputs, **kwargs)
+        with spans.span("serve.decide", kind="spec_depth"):
+            evidence = (self.spec_accept_key, self.spec_step_key,
+                        self.fused_key)
+            inputs: tuple = ()
+            ema = model.smoothed_t_iter(self.spec_accept_key)
+            if ema is None:
+                accept = DEFAULT_SPEC_ACCEPT
+                inputs += (("seeded", True),)
+            else:
+                # The EMA already holds the acceptance rate (recovered at
+                # each dispatch's own width in ``_drain``) — no inversion.
+                accept = min(max(float(ema), 0.0), 0.999)
+                inputs += (("accept_ema", round(float(ema), 4)),)
+            step = model.smoothed_t_iter(self.spec_step_key) \
+                or model.smoothed_t_iter(self.fused_key) or 0.0
+            cands = tuple(c for c in SPEC_DEPTH_CANDIDATES if c <= cap) \
+                or (1,)
+            # Width cost measured, not assumed: the EMA'd speculative round
+            # seconds over the EMA'd width-1 iteration seconds prices the
+            # wider verify on *this* host and config (on CPU a width-2 GEMM
+            # can even beat the width-1 GEMV — the static prior cannot know
+            # that).  Falls back to the analytic prior until both step EMAs
+            # hold samples.
+            kwargs: dict = {}
+            spec_s = model.smoothed_t_iter(self.spec_step_key)
+            iter_s = model.smoothed_t_iter(self.fused_iter_key)
+            if spec_s and iter_s:
+                d_ref = max(self._spec_depth, 2)
+                wc = (spec_s / iter_s - 1.0) / (d_ref - 1.0)
+                kwargs["width_cost"] = min(max(wc, 0.0), 1.0)
+                inputs += (("width_cost_online", True),)
+            decision = model.spec_depth(
+                self.spec_depth_key, candidates=cands, accept_rate=accept,
+                step_s=step, max_depth=cap, current=self._spec_depth,
+                evidence=evidence, inputs=inputs, **kwargs)
         return decision.chunk
 
     def spec_stats(self) -> dict:
@@ -1343,27 +1409,28 @@ class ServeScheduler:
             return depth
         if model is None:     # static params object: no store to consult
             return min(8, self.max_dispatch_depth)
-        evidence = [self.host_tick_key, self.fused_key]
-        inputs: tuple = ()
-        host = model.smoothed_t_iter(self.host_tick_key)
-        if host is None:
-            t0 = self.acc.calibrate_t0(self.executor)
-            host = t0 + 4.0 * decision_overhead_s()
-            inputs = (("seeded", True),)
-        dev = model.smoothed_t_iter(self.fused_key)
-        if dev is None:
-            # Fall back to the per-tick decode key's smoothed value, or
-            # the analytic roofline profile behind it.
-            dev = self.acc.measure_iteration(
-                self.executor, self.decode_profile, max(len(decs), 1),
-                key=self.decode_key)
-            evidence.append(self.decode_key)
-        decision = model.dispatch_depth(
-            self.depth_key, host_overhead_s=host, device_step_s=dev,
-            max_depth=self.max_dispatch_depth,
-            eff=getattr(self.acc, "efficiency",
-                        overhead_law.DEFAULT_EFFICIENCY),
-            evidence=tuple(evidence), inputs=inputs)
+        with spans.span("serve.decide", kind="depth"):
+            evidence = [self.host_tick_key, self.fused_key]
+            inputs: tuple = ()
+            host = model.smoothed_t_iter(self.host_tick_key)
+            if host is None:
+                t0 = self.acc.calibrate_t0(self.executor)
+                host = t0 + 4.0 * decision_overhead_s()
+                inputs = (("seeded", True),)
+            dev = model.smoothed_t_iter(self.fused_key)
+            if dev is None:
+                # Fall back to the per-tick decode key's smoothed value, or
+                # the analytic roofline profile behind it.
+                dev = self.acc.measure_iteration(
+                    self.executor, self.decode_profile, max(len(decs), 1),
+                    key=self.decode_key)
+                evidence.append(self.decode_key)
+            decision = model.dispatch_depth(
+                self.depth_key, host_overhead_s=host, device_step_s=dev,
+                max_depth=self.max_dispatch_depth,
+                eff=getattr(self.acc, "efficiency",
+                            overhead_law.DEFAULT_EFFICIENCY),
+                evidence=tuple(evidence), inputs=inputs)
         return decision.chunk
 
     def _decide_page_size(self) -> int:
@@ -1379,20 +1446,21 @@ class ServeScheduler:
         model = self.decision_model()
         if model is None:
             return 16
-        mgmt = model.smoothed_t_iter(self.page_mgmt_key) or 0.0
-        pf = model.smoothed_t_iter(self.prefill_key)
-        inputs: tuple = ()
-        if pf is None:
-            pf = self.acc.measure_iteration(
-                self.executor, self.prefill_profile, self.max_len,
-                key=self.prefill_key)
-            inputs = (("seeded", True),)
-        decision = model.page_size(
-            self.page_size_key, candidates=DEFAULT_PAGE_CANDIDATES,
-            max_len=self.max_len, page_mgmt_s=mgmt,
-            prefill_token_s=pf or 0.0,
-            evidence=(self.page_mgmt_key, self.prefill_key),
-            inputs=inputs)
+        with spans.span("serve.decide", kind="page_size"):
+            mgmt = model.smoothed_t_iter(self.page_mgmt_key) or 0.0
+            pf = model.smoothed_t_iter(self.prefill_key)
+            inputs: tuple = ()
+            if pf is None:
+                pf = self.acc.measure_iteration(
+                    self.executor, self.prefill_profile, self.max_len,
+                    key=self.prefill_key)
+                inputs = (("seeded", True),)
+            decision = model.page_size(
+                self.page_size_key, candidates=DEFAULT_PAGE_CANDIDATES,
+                max_len=self.max_len, page_mgmt_s=mgmt,
+                prefill_token_s=pf or 0.0,
+                evidence=(self.page_mgmt_key, self.prefill_key),
+                inputs=inputs)
         return decision.chunk
 
     def _decide_interleave(self, chunk: int) -> int:
@@ -1417,25 +1485,26 @@ class ServeScheduler:
         model = self.decision_model()
         if model is None:
             return cap
-        n_dec = sum(1 for r in self._active
-                    if r.state is RequestState.DECODE)
-        dev = model.smoothed_t_iter(self.fused_key) or 0.0
-        window = dev * max(self._last_depth, 1) * max(n_dec, 1)
-        t_pf = model.smoothed_t_iter(self.prefill_key)
-        inputs: tuple = (("depth", self._last_depth), ("lanes", n_dec))
-        if t_pf is None:
-            t_pf = self.acc.measure_iteration(
-                self.executor, self.prefill_profile, max(chunk, 1),
-                key=self.prefill_key)
-            inputs += (("seeded", True),)
-        decision = model.prefill_interleave(
-            self.interleave_key, pending_chunks=ready,
-            decode_window_s=window,
-            chunk_cost_s=max(t_pf or 0.0, 0.0) * max(chunk, 1),
-            max_chunks=self.pool.n_slots,
-            evidence=(self.fused_key, self.prefill_key,
-                      self.host_tick_key),
-            inputs=inputs)
+        with spans.span("serve.decide", kind="interleave"):
+            n_dec = sum(1 for r in self._active
+                        if r.state is RequestState.DECODE)
+            dev = model.smoothed_t_iter(self.fused_key) or 0.0
+            window = dev * max(self._last_depth, 1) * max(n_dec, 1)
+            t_pf = model.smoothed_t_iter(self.prefill_key)
+            inputs: tuple = (("depth", self._last_depth), ("lanes", n_dec))
+            if t_pf is None:
+                t_pf = self.acc.measure_iteration(
+                    self.executor, self.prefill_profile, max(chunk, 1),
+                    key=self.prefill_key)
+                inputs += (("seeded", True),)
+            decision = model.prefill_interleave(
+                self.interleave_key, pending_chunks=ready,
+                decode_window_s=window,
+                chunk_cost_s=max(t_pf or 0.0, 0.0) * max(chunk, 1),
+                max_chunks=self.pool.n_slots,
+                evidence=(self.fused_key, self.prefill_key,
+                          self.host_tick_key),
+                inputs=inputs)
         return decision.chunk
 
     def _dispatch_decode(self):
@@ -1446,6 +1515,12 @@ class ServeScheduler:
         decs = [r for r in self._active if r.state is RequestState.DECODE]
         if not decs:
             return [], [], 0, 0, 0
+        with spans.span("serve.dispatch") as sp:
+            return self._dispatch(decs, sp)
+
+    def _dispatch(self, decs: list, sp) -> tuple:
+        """``_dispatch_decode`` inside its ``serve.dispatch`` span ``sp``,
+        which gets the dispatch's lane counts (``_count_lanes``)."""
         depth = self._decide_depth(decs)
         self._last_depth = depth
         spec_d = self._spec_depth if self._spec else 1
@@ -1502,25 +1577,27 @@ class ServeScheduler:
             self.decode_dispatches % self.sync_every == 0
         if timed:
             self._drain(drop_to=0)
-        t_dev = time.perf_counter()
-        pt = (self.pool.page_table_array(),) if self.paged else ()
-        stats = None
-        if use_spec:
-            new_caches, new_hist, out_buf, final_toks, stats = fused(
-                self.params, self.pool.caches, *pt, self._decode_hist(),
-                toks_a, poss_a, steps_a)
-        else:
-            new_hist = None
-            new_caches, out_buf, final_toks = fused(
-                self.params, self.pool.caches, *pt, toks_a, poss_a,
-                steps_a)
-        self.pool.mark_donated("fused decode dispatch")
+        with spans.span("serve.dispatch.launch", synced=timed) as launch:
+            pt = (self.pool.page_table_array(),) if self.paged else ()
+            stats = None
+            if use_spec:
+                new_caches, new_hist, out_buf, final_toks, stats = fused(
+                    self.params, self.pool.caches, *pt,
+                    self._decode_hist(), toks_a, poss_a, steps_a)
+            else:
+                new_hist = None
+                new_caches, out_buf, final_toks = fused(
+                    self.params, self.pool.caches, *pt, toks_a, poss_a,
+                    steps_a)
+            self.pool.mark_donated("fused decode dispatch")
+            if timed:
+                # The periodic honest-timing sync (one per
+                # ``sync_every`` dispatches) — budgeted by design, see
+                # class docstring.
+                jax.block_until_ready(out_buf)  # repro-lint: disable=RL002
         total = sum(take for _, _, take in lanes)
         if timed:
-            # The periodic honest-timing sync (one per ``sync_every``
-            # dispatches) — budgeted by design, see class docstring.
-            jax.block_until_ready(out_buf)  # repro-lint: disable=RL002
-            dt = time.perf_counter() - t_dev
+            dt = launch.end - launch.start
             self._blocked_s += dt
             self.host_roundtrips += 1
             model = self.decision_model()
@@ -1563,13 +1640,20 @@ class ServeScheduler:
             self._dev_hist = new_hist
         self.decode_dispatches += 1
         self.decode_tokens += total
+        # What every other slot holds while this dispatch's loop runs:
+        # a request in prefill, or nothing (with requests waiting or not).
+        others = (sum(1 for r in self._active
+                      if r.state is RequestState.PREFILL),
+                  self.pool.free_slots(), bool(self._waiting))
+        sp.attrs.update(depth=depth, tokens=total)
         if stats is None:
-            self.decode_loop_iters += max((take for _, _, take in lanes),
-                                          default=0)
+            iters = max((take for _, _, take in lanes), default=0)
+            self.decode_loop_iters += iters
+            self._count_lanes(sp, iters, total, len(lanes), *others)
         # else: speculative loop rounds are variable — counted at drain
-        # time from the dispatch's stats vector.
+        # time from the dispatch's stats vector, lanes with them.
         self._inflight.append(
-            (out_buf, stats, spec_d if use_spec else 0, lanes))
+            (out_buf, stats, spec_d if use_spec else 0, lanes, others))
 
         decoded, finished = [], []
         for r, slot, take in lanes:
@@ -1582,6 +1666,22 @@ class ServeScheduler:
                 finished.append(r.rid)
         return decoded, finished, depth, total, spec_d if use_spec else 0
 
+    def _count_lanes(self, sp, iters: int, decode: int, n_lanes: int,
+                     n_prefill: int, n_free: int, queued: bool) -> None:
+        """Count one dispatch's ``iters`` loop iterations for every slot
+        under one of ``LANES``, into ``lane_iters`` and onto the span
+        ``sp``.  ``decode`` of the ``iters x n_lanes`` decoding
+        lane-iterations produced a token; the rest ran clamped.  Under
+        speculation an iteration is one verify round, and ``decode`` the
+        lanes' verify events (each emits one to ``d`` tokens)."""
+        counts = (decode, iters * n_lanes - decode, iters * n_prefill,
+                  iters * n_free if queued else 0,
+                  0 if queued else iters * n_free)
+        sp.attrs["iters"] = sp.attrs.get("iters", 0) + iters
+        for kind, attr, n in zip(LANES, _LANE_ATTRS, counts, strict=True):
+            self.lane_iters[kind] += n
+            sp.attrs[attr] = sp.attrs.get(attr, 0) + n
+
     def _drain(self, drop_to: int | None = None,
                harvest: bool = False) -> None:
         """Land emitted tokens from finished fused dispatches.
@@ -1590,7 +1690,24 @@ class ServeScheduler:
         flight (the pipeline bound); ``harvest`` additionally pops any
         buffer that is already materialised, without blocking.  One
         ``device_get`` per dispatch — the fused path's only routine
-        host round-trip."""
+        host round-trip.  The dispatches landed, and whether landing
+        them blocked, ride on the call's ``serve.drain`` span; when it
+        blocked, the span is device wait (``_blocked_s``)."""
+        if not self._inflight:
+            return
+        with spans.span("serve.drain") as sp:
+            landed = self._fetch(drop_to, harvest)
+            sp.attrs["landed"] = len(landed)
+            sp.attrs["blocked"] = blocked = any(must for *_, must in landed)
+            for entry, fetched, at, _ in landed:
+                self._land(sp, entry, fetched, at)
+        if blocked:
+            self._blocked_s += sp.end - sp.start
+
+    def _fetch(self, drop_to: int | None, harvest: bool) -> list:
+        """Pop and fetch the dispatches ``_drain`` lands: ``(entry,
+        host buffers, landing time, whether it had to wait)`` each."""
+        landed = []
         while self._inflight:
             must = drop_to is not None and len(self._inflight) > drop_to
             if not must:
@@ -1599,59 +1716,68 @@ class ServeScheduler:
                 probe = getattr(self._inflight[0][0], "is_ready", None)
                 if probe is not None and not probe():
                     break
-            out_buf, stats, disp_spec_d, lanes = self._inflight.popleft()
-            t_dev = time.perf_counter()
+            entry = self._inflight.popleft()
+            out_buf, stats = entry[0], entry[1]
             # The fused path's one sanctioned round-trip (docstring above).
             if stats is not None:
-                toks, st = jax.device_get(  # repro-lint: disable=RL002
+                fetched = jax.device_get(  # repro-lint: disable=RL002
                     (out_buf, stats))
             else:
-                toks = jax.device_get(out_buf)  # repro-lint: disable=RL002
-                st = None
-            if must:
-                self._blocked_s += time.perf_counter() - t_dev
+                fetched = (jax.device_get(  # repro-lint: disable=RL002
+                    out_buf), None)
+            landed.append((entry, fetched, self.clock(), must))
             self.host_roundtrips += 1
-            if st is not None:
-                # Speculation telemetry: loop rounds actually run,
-                # per-lane verify events, and tokens they emitted.  The
-                # acceptance rate is recovered *here*, at the width this
-                # dispatch actually ran (``disp_spec_d``), not later at
-                # whatever width the scheduler has since moved to —
-                # tokens-per-verify saturates at the dispatch width, so
-                # inverting it at any other width mis-reads acceptance.
-                # Stored as elems=verifies, seconds=accept × verifies so
-                # the EMA's per-element ratio *is* the acceptance rate.
-                rounds, verifies, emitted = (int(x) for x in st)
-                self.decode_loop_iters += rounds
-                self.spec_rounds += rounds
-                self.spec_verifies += verifies
-                self.spec_emitted += emitted
-                model = self.decision_model()
-                if model is not None and verifies > 0 \
-                        and disp_spec_d >= 2:
-                    a_s = (emitted / verifies - 1.0) / (disp_spec_d - 1.0)
-                    # Floor keeps the sample visible to the EMA (the
-                    # refiner drops zero-cost observations) while
-                    # staying far below the backoff threshold.
-                    a_s = min(max(a_s, 1e-3), 0.999)
-                    model.observe(self.spec_accept_key, verifies,
-                                  a_s * verifies)
-            for req, slot, take in lanes:
-                req.pending_out -= take
-                if req.state is RequestState.CANCELLED:
-                    # Dispatched before the cancel landed: the buffer is
-                    # drained (the slot bookkeeping must balance) but
-                    # the tokens are dropped, never emitted.
-                    continue
-                req.out.extend(
-                    # ``toks`` is host numpy already — not a device sync.
-                    int(toks[j, slot])  # repro-lint: disable=RL002
-                    for j in range(take))
-                if req.state is RequestState.DONE \
-                        and req.pending_out <= 0 \
-                        and req.finished_at is None:
-                    req.out = req.out[:req.max_new_tokens]
-                    self._stamp_finished(req)
+        return landed
+
+    def _land(self, sp, entry: tuple, fetched: tuple, at: float) -> None:
+        """Land one fetched dispatch: its tokens (stamped ``at``, when
+        they reached the host) and, under speculation, its telemetry
+        and lane counts (on the drain's span ``sp``)."""
+        _, _, disp_spec_d, lanes, others = entry
+        toks, st = fetched
+        if st is not None:
+            # Speculation telemetry: loop rounds actually run,
+            # per-lane verify events, and tokens they emitted.  The
+            # acceptance rate is recovered *here*, at the width this
+            # dispatch actually ran (``disp_spec_d``), not later at
+            # whatever width the scheduler has since moved to —
+            # tokens-per-verify saturates at the dispatch width, so
+            # inverting it at any other width mis-reads acceptance.
+            # Stored as elems=verifies, seconds=accept × verifies so
+            # the EMA's per-element ratio *is* the acceptance rate.
+            rounds, verifies, emitted = (int(x) for x in st)
+            self.decode_loop_iters += rounds
+            self.spec_rounds += rounds
+            self.spec_verifies += verifies
+            self.spec_emitted += emitted
+            self._count_lanes(sp, rounds, verifies, len(lanes), *others)
+            model = self.decision_model()
+            if model is not None and verifies > 0 \
+                    and disp_spec_d >= 2:
+                a_s = (emitted / verifies - 1.0) / (disp_spec_d - 1.0)
+                # Floor keeps the sample visible to the EMA (the
+                # refiner drops zero-cost observations) while
+                # staying far below the backoff threshold.
+                a_s = min(max(a_s, 1e-3), 0.999)
+                model.observe(self.spec_accept_key, verifies,
+                              a_s * verifies)
+        for req, slot, take in lanes:
+            req.pending_out -= take
+            if req.state is RequestState.CANCELLED:
+                # Dispatched before the cancel landed: the buffer is
+                # drained (the slot bookkeeping must balance) but
+                # the tokens are dropped, never emitted.
+                continue
+            req.out.extend(
+                # ``toks`` is host numpy already — not a device sync.
+                int(toks[j, slot])  # repro-lint: disable=RL002
+                for j in range(take))
+            req.landed_at.extend([at] * take)
+            if req.state is RequestState.DONE \
+                    and req.pending_out <= 0 \
+                    and req.finished_at is None:
+                self._truncate(req)
+                self._stamp_finished(req)
 
     def flush(self) -> None:
         """Block until every in-flight fused dispatch has drained."""
@@ -1661,16 +1787,34 @@ class ServeScheduler:
         req.state = RequestState.DONE
         self.pool.release(req.slot)
         if req.pending_out <= 0:
-            req.out = req.out[:req.max_new_tokens]
+            self._truncate(req)
             self._stamp_finished(req)
         # else: the drain that lands the final tokens truncates at the
         # stop point and stamps finished_at (serve/decode_loop.py).
+
+    @staticmethod
+    def _truncate(req: Request) -> None:
+        """Cut the output (and its landing stamps) at the stop point."""
+        req.out = req.out[:req.max_new_tokens]
+        req.landed_at = req.landed_at[:req.max_new_tokens]
 
     def _stamp_finished(self, req: Request) -> None:
         """Stamp completion time and charge a deadline miss if the
         request's tokens landed past its deadline (SLO accounting: a
         late completion is wasted work, same as a shed)."""
         req.finished_at = self.clock()
+        self._record_request(req)
         if req.deadline is not None and req.finished_at > req.deadline:
             self.deadline_misses += 1
             self._tick_misses += 1
+
+    @staticmethod
+    def _record_request(req: Request) -> None:
+        """The request's ``serve.request`` span, written once it is
+        terminal: submitted (``arrival``) to finished, with its
+        admission and first-token stamps (scheduler clock)."""
+        spans.record("serve.request", req.arrival, req.finished_at,
+                     rid=req.rid, state=req.state.value,
+                     submitted=req.arrival, admitted=req.admitted_at,
+                     first_token=req.first_token_at,
+                     finished=req.finished_at, tokens=len(req.out))
