@@ -29,11 +29,19 @@ host-emulated serving mesh — see tests/test_analysis.py):
   ``collective-permute`` in the body always mean the partitioner moved
   the carry; on a single device (no mesh) *any* collective is a bug.
 
-Plain ``copy`` ops inside the body are counted and reported (the
-donated carry legitimately materialises row copies ahead of
-``dynamic-update-slice``), but are not a failure by themselves —
-copy-based resharding on one device cannot be told apart from those by
-text alone, which is exactly why the strict-mode runtime guards exist.
+Plain ``copy`` ops inside the body are counted and reported, but are
+not a failure by themselves — copy-based resharding on one device
+cannot be told apart from layout copies by text alone, which is exactly
+why the strict-mode runtime guards exist.
+
+The decode step writes one K/V token per active lane into the carried
+pool in place, so nothing in the body needs a buffer the size of a pool
+leaf.  ``pool_selects`` and ``pool_copies`` count the body's ``select``
+and ``copy`` ops whose result has a pool leaf's shape: 0 when the
+in-place write engaged, ``2 x n_attn_layers`` of either when a step
+rewrites the whole pool through a ``where`` or copies its carry.  A
+full-row merge (speculative verify rows, recurrent states) selects by
+design and is counted too.
 
 CLI::
 
@@ -61,6 +69,9 @@ _BODY_RE = re.compile(r"body=%?([\w.\-]+)")
 _REF_RE = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
 _WHILE_RE = re.compile(r"\bwhile\(")
 _COPY_RE = re.compile(r"\bcopy\(")
+# An op's result shape and name: ``= f32[4,2,256,16]{3,2,1,0} select(``
+# (a TPU layout adds tiling in the braces).
+_RESULT_RE = re.compile(r"=\s*\w+\[([\d,]*)\](?:\{[^}]*\})?\s+(select|copy)\(")
 
 # The argmax lane gathers per-lane (max, argmax) scalars across the
 # vocab shards — bytes, not buffers.  A gathered cache row is KiB+.
@@ -157,6 +168,25 @@ class AuditPolicy:
                 f"collective is predicted without a mesh")
 
 
+def pool_shaped_ops(bodies: dict[str, str],
+                    pool_shapes: set[tuple[int, ...]]) -> dict[str, int]:
+    """``{"select": n, "copy": n}``: the loop bodies' ops of those kinds
+    whose result has one of ``pool_shapes``.  A computation a nested
+    loop shares with its parent's body is counted once."""
+    seen: set[str] = set()
+    out = {"select": 0, "copy": 0}
+    for text in bodies.values():
+        for line in text.splitlines():
+            m = _RESULT_RE.search(line)
+            if m is None or line in seen:
+                continue
+            seen.add(line)
+            shape = tuple(int(x) for x in m.group(1).split(",") if x)
+            if shape in pool_shapes:
+                out[m.group(2)] += 1
+    return out
+
+
 @dataclasses.dataclass
 class AuditReport:
     n_bodies: int
@@ -164,6 +194,8 @@ class AuditReport:
     violations: list[tuple[LoopOp, str]]
     copy_count: int
     copy_bytes: int
+    pool_selects: int = 0
+    pool_copies: int = 0
 
     @property
     def ok(self) -> bool:
@@ -182,6 +214,8 @@ class AuditReport:
             "collective_counts": self.counts(),
             "copy_count": self.copy_count,
             "copy_bytes": self.copy_bytes,
+            "pool_selects": self.pool_selects,
+            "pool_copies": self.pool_copies,
             "violations": [
                 {"body": op.body, "kind": op.kind,
                  "result_bytes": op.result_bytes,
@@ -190,8 +224,12 @@ class AuditReport:
         }
 
 
-def audit_hlo(hlo_text: str, policy: AuditPolicy) -> AuditReport:
-    """Audit every ``while`` body in ``hlo_text`` against ``policy``."""
+def audit_hlo(hlo_text: str, policy: AuditPolicy,
+              pool_shapes: set[tuple[int, ...]] = frozenset()
+              ) -> AuditReport:
+    """Audit every ``while`` body in ``hlo_text`` against ``policy``;
+    ``pool_shapes`` (the per-device shapes of the pool's leaves) feeds
+    the pool-shaped ``select``/``copy`` counts."""
     collectives: list[LoopOp] = []
     violations: list[tuple[LoopOp, str]] = []
     copy_count = copy_bytes = 0
@@ -205,9 +243,12 @@ def audit_hlo(hlo_text: str, policy: AuditPolicy) -> AuditReport:
         for op in _scan_ops(name, text, _COPY_RE, kind="copy"):
             copy_count += 1
             copy_bytes += op.result_bytes
+    pooled = pool_shaped_ops(bodies, set(pool_shapes))
     return AuditReport(n_bodies=len(bodies), collectives=collectives,
                        violations=violations, copy_count=copy_count,
-                       copy_bytes=copy_bytes)
+                       copy_bytes=copy_bytes,
+                       pool_selects=pooled["select"],
+                       pool_copies=pooled["copy"])
 
 
 def audit_scheduler(sched, *, inject_reshard: bool = False,
@@ -266,14 +307,29 @@ def audit_scheduler(sched, *, inject_reshard: bool = False,
         model_parallel = int(dict(sched.mesh.shape).get("model", 1))
     return audit_hlo(lowered.compile().as_text(),
                      AuditPolicy(model_parallel=model_parallel,
-                                 small_gather_max=small_gather_max))
+                                 small_gather_max=small_gather_max),
+                     pool_shapes=pool_leaf_shapes(sched.pool))
+
+
+def pool_leaf_shapes(pool) -> set[tuple[int, ...]]:
+    """Per-device shapes of the pool's leaves (a sharded leaf's shard
+    shape: the post-partitioning HLO holds shards)."""
+    import jax
+
+    leaves = jax.tree.leaves(pool.caches)
+    shardings = jax.tree.leaves(pool.shardings) \
+        if pool.shardings is not None else [None] * len(leaves)
+    return {tuple(x.shape) if sh is None else tuple(sh.shard_shape(x.shape))
+            for x, sh in zip(leaves, shardings, strict=True)}
 
 
 def format_report(report: AuditReport) -> str:
     lines = [f"hlo-audit: {report.n_bodies} loop body(ies), "
              f"collectives={report.counts() or '{}'}, "
              f"copies={report.copy_count} "
-             f"({report.copy_bytes} B result)"]
+             f"({report.copy_bytes} B result), "
+             f"pool-shaped selects={report.pool_selects} "
+             f"copies={report.pool_copies}"]
     for op, reason in report.violations:
         lines.append(f"  VIOLATION [{op.body}] {reason}")
         lines.append(f"    {op.text[:140]}")

@@ -9,7 +9,7 @@ Three softmax-attention implementations share one signature:
 """
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -160,11 +160,17 @@ def self_attention(p: dict, x: jax.Array, cfg: ArchConfig, *,
                    positions: jax.Array, window: int | None,
                    impl: str = "chunked",
                    cache: dict | None = None,
-                   cache_pos: Any = None) -> tuple[jax.Array, dict | None]:
+                   cache_pos: Any = None, stepwise: bool = False
+                   ) -> tuple[jax.Array, dict | None]:
     """x: (B, S, d).  Without a cache: full causal self-attention (train /
-    one-shot prefill).  With a cache: write K/V at ``cache_pos`` (ring
-    slot for SWA) and attend against the whole cache (decode / chunked
-    prefill)."""
+    one-shot prefill).  With a cache and S == 1 (decode): attend the
+    cache as it stands plus the new token, and return the write as a
+    ``KVWrite`` per leaf rather than a written cache (``lm.commit`` makes
+    it).  With a cache and S > 1: write K/V at ``cache_pos`` (ring slot
+    for SWA) and attend the written cache — causally (chunked prefill),
+    or, ``stepwise``, each query over the rows before it plus its own
+    token as decode does (speculative verify, which must agree with
+    decode token for token)."""
     cd = jnp.dtype(cfg.compute_dtype)
     hd = cfg.head_dim_
     q = _split_heads(cm.linear(p["wq"], x, cd), cfg.n_heads, hd)
@@ -183,21 +189,35 @@ def self_attention(p: dict, x: jax.Array, cfg: ArchConfig, *,
     else:
         s_cache = cache["k"].shape[2]
         slot = cache_pos % s_cache if window is not None else cache_pos
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, 0, slot, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, 0, slot, 0))
-        new_cache = {"k": ck, "v": cv}
-        kv_len = jnp.minimum(cache_pos + q.shape[2], s_cache)
-        if window is None:
-            # Decode attention is a memory-bound GEMV over the cache; the
-            # einsum-softmax form partitions cleanly when the cache seq dim
-            # is sharded (long-context SP), unlike a kv-block scan.
-            out = _cache_attention(q, ck, cv, kv_len, causal=True)
+        kt = k.astype(cache["k"].dtype)
+        vt = v.astype(cache["v"].dtype)
+        if q.shape[2] == 1:
+            # Decode: attend the cache as it stands plus the new token,
+            # and hand the write back instead of a written cache.
+            out = _step_attention(
+                q, cache["k"], cache["v"], kt, vt, cache_pos,
+                replaced=slot if window is not None else None)
+            at = jnp.full((q.shape[0],), slot, jnp.int32)
+            new_cache = {"k": KVWrite(kt[:, :, 0], at),
+                         "v": KVWrite(vt[:, :, 0], at)}
         else:
-            # Ring buffer: every populated slot is within the window by
-            # construction (cache length == window).
-            out = _ring_attention(q, ck, cv, cache_pos, s_cache)
+            ck = jax.lax.dynamic_update_slice(cache["k"], kt,
+                                              (0, 0, slot, 0))
+            cv = jax.lax.dynamic_update_slice(cache["v"], vt,
+                                              (0, 0, slot, 0))
+            new_cache = {"k": ck, "v": cv}
+            if stepwise:
+                out = _step_attention(q, ck, cv, kt, vt, cache_pos)
+            elif window is None:
+                # The einsum-softmax form partitions cleanly when the
+                # cache seq dim is sharded (long-context SP), unlike a
+                # kv-block scan.
+                kv_len = jnp.minimum(cache_pos + q.shape[2], s_cache)
+                out = _cache_attention(q, ck, cv, kv_len, causal=True)
+            else:
+                # Ring buffer: every populated slot is within the window
+                # by construction (cache length == window).
+                out = _ring_attention(q, ck, cv, cache_pos, s_cache)
     out = cm.linear(p["wo"], _merge_heads(out), cd)
     return out, new_cache
 
@@ -218,6 +238,39 @@ def _cache_attention(q, ck, cv, kv_len, *, causal: bool):
     s = jnp.where(mask[None, None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", p, cv.astype(jnp.float32))
+    return out.reshape(b, hq, sq, d).astype(q.dtype)
+
+
+def _step_attention(q, ck, cv, k, v, pos, *, replaced=None):
+    """Einsum-softmax attention of each query over the cache rows before
+    its position, joined in one softmax with its own key and value: the
+    decode form.
+
+    q: (B, Hq, L, D) at positions ``pos .. pos + L - 1``; ck/cv: (B, Hkv,
+    S, D); k/v: (B, Hkv, L, D), the queries' own keys and values in the
+    cache's dtype.  Query ``r`` reads cache row ``j`` when ``j < pos + r``
+    and ``j != replaced`` (the ring row a decode token overwrites).  The
+    rows a query reads hold the same values whether its chunk was written
+    first (a speculative verify) or not (decode), so the two agree token
+    for token.  Decode attention is a memory-bound GEMV over the cache."""
+    b, hq, sq, d = q.shape
+    hkv, s_cache = ck.shape[1], ck.shape[2]
+    group = hq // hkv
+    qg = q.reshape(b, hkv, group, sq, d).astype(jnp.float32) / (d ** 0.5)
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, ck.astype(jnp.float32))
+    kj = jnp.arange(s_cache)[None, :]
+    mask = kj < pos + jnp.arange(sq)[:, None]
+    if replaced is not None:
+        mask = mask & (kj != replaced)
+    s = jnp.where(mask, s, -jnp.inf)
+    s_own = jnp.einsum("bhgqd,bhqd->bhgq", qg,
+                       k.astype(jnp.float32))[..., None]
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s_own)
+    p = jnp.exp(s - m)
+    p_own = jnp.exp(s_own - m)
+    denom = jnp.sum(p, axis=-1, keepdims=True) + p_own
+    out = (jnp.einsum("bhgqk,bhkd->bhgqd", p, cv.astype(jnp.float32))
+           + p_own * v.astype(jnp.float32)[:, :, None]) / denom
     return out.reshape(b, hq, sq, d).astype(q.dtype)
 
 
@@ -255,6 +308,49 @@ def cross_attention(p: dict, x: jax.Array, kv_feats: jax.Array,
     out = sdpa(q, k, v, impl="chunked" if impl == "flash" else impl,
                causal=False)
     return cm.linear(p["wo"], _merge_heads(out), cd)
+
+
+class KVWrite(NamedTuple):
+    """A decode step's cache write, not yet made: each batch row's new K
+    or V token ``(B, H_kv, D)`` and the cache row it goes to, ``(B,)``
+    (``pos``, or ``pos % s_cache`` in a ring)."""
+
+    token: jax.Array
+    at: jax.Array
+
+
+def write_tokens(caches: list, writes: list, keep: jax.Array) -> list:
+    """Write each batch row's token of every ``KVWrite`` in ``writes`` into
+    its cache ``(B, H_kv, S, D)`` at the row ``at`` — in place when the
+    caches are donated buffers or a loop carry.  Only the batch rows whose
+    ``keep`` is True are visited, one after another in one loop over all
+    the caches; every other row's bytes stay as they were."""
+    order = jnp.argsort(~keep, stable=True)     # the rows to write first
+
+    def put(i, cs):
+        b = order[i]
+        return [_put_row(c, w, b) for c, w in zip(cs, writes, strict=True)]
+
+    return jax.lax.fori_loop(0, jnp.sum(keep, dtype=jnp.int32), put,
+                             list(caches))
+
+
+def _put_row(cache, w: KVWrite, b):
+    """Batch row ``b`` of ``w`` into ``cache``.  The write covers the
+    aligned block of rows around ``at`` that fills one tile of the TPU's
+    layout (8 x 128 words: 32 // itemsize rows), setting one row of it: a
+    one-row update makes the compiler lay the whole cache out by heads
+    and copy it at every step.  An ``at`` past the cache changes
+    nothing."""
+    _, h, s, d = cache.shape
+    t = min(32 // cache.dtype.itemsize, s)
+    at = w.at[b]
+    start = jnp.clip((at // t) * t, 0, s - t)
+    block = jax.lax.dynamic_slice(cache, (b, 0, start, 0), (1, h, t, d))
+    row = (jnp.arange(t) == at - start)[None, None, :, None]
+    tok = w.token[b].astype(cache.dtype)[None, :, None, :]
+    return jax.lax.dynamic_update_slice(
+        cache, jnp.where(row, tok, block), (b, 0, start, 0))
 
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int, *,

@@ -8,10 +8,14 @@ stacked over ``n_layers // period`` groups and the group body is compiled
 once — essential for 88-layer dry-run compiles.  Leftover layers (when
 period ∤ n_layers) run unscanned.
 
-Two entry points:
+Entry points:
   * ``forward``      — full-sequence (train / one-shot prefill), scan path.
   * ``forward_cached`` — serve path with per-layer caches (KV ring buffers,
     SSM/xLSTM states), python loop over layers.
+  * ``forward_step`` — ``forward_cached`` that leaves the caches as they
+    are and returns each layer's update; a one-token step's attention
+    update is the token it writes (``attention.KVWrite``), for the
+    caller to write in place (``commit``, ``decode_loop.masked_merge``).
 """
 from __future__ import annotations
 
@@ -104,12 +108,13 @@ def init_params(key, cfg: ArchConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 def _apply_shared(shared: dict, proj: dict, x, cfg: ArchConfig, *,
-                  positions, window, impl, cache=None, cache_pos=None):
+                  positions, window, impl, cache=None, cache_pos=None,
+                  stepwise=False):
     h = cm.rmsnorm(shared["ln1"], x, cfg.norm_eps)
     with jax.named_scope("attention"):
         a, new_cache = attention.self_attention(
             shared["attn"], h, cfg, positions=positions, window=window,
-            impl=impl, cache=cache, cache_pos=cache_pos)
+            impl=impl, cache=cache, cache_pos=cache_pos, stepwise=stepwise)
     h = h + a
     h2 = cm.rmsnorm(shared["ln2"], h, cfg.norm_eps)
     with jax.named_scope("mlp"):
@@ -119,7 +124,7 @@ def _apply_shared(shared: dict, proj: dict, x, cfg: ArchConfig, *,
 
 def _apply_block(kind: str, p: dict, x, cfg: ArchConfig, *,
                  positions, window, impl, shared=None, frontend_feats=None,
-                 cache=None, cache_pos=None):
+                 cache=None, cache_pos=None, stepwise=False):
     """Returns (x, aux_loss, new_cache)."""
     cd = jnp.dtype(cfg.compute_dtype)
     aux = jnp.zeros((), jnp.float32)
@@ -129,7 +134,8 @@ def _apply_block(kind: str, p: dict, x, cfg: ArchConfig, *,
         with jax.named_scope("attention"):
             a, new_cache = attention.self_attention(
                 p["attn"], h, cfg, positions=positions, window=window,
-                impl=impl, cache=cache, cache_pos=cache_pos)
+                impl=impl, cache=cache, cache_pos=cache_pos,
+                stepwise=stepwise)
         x = x + a
         h = cm.rmsnorm(p["ln2"], x, cfg.norm_eps)
         with jax.named_scope("mlp"):
@@ -151,7 +157,8 @@ def _apply_block(kind: str, p: dict, x, cfg: ArchConfig, *,
     elif kind == "shared_attn":
         x, new_cache = _apply_shared(
             shared, p["proj"], x, cfg, positions=positions,
-            window=window, impl=impl, cache=cache, cache_pos=cache_pos)
+            window=window, impl=impl, cache=cache, cache_pos=cache_pos,
+            stepwise=stepwise)
     elif kind == "mamba2":
         h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
         y, new_cache = mamba2.apply(p["mamba"], h, cfg, state=cache)
@@ -266,30 +273,76 @@ def forward_cached(params: dict, tokens: jax.Array, caches: list, pos,
     length and needs the logits of the last *real* token.  With
     ``all_logits`` the head runs over every fed position (``(B, L_new,
     V)``) — the speculative verify needs the model's prediction after
-    each draft token in one batched forward."""
+    each draft token in one batched forward, and each position then
+    attends as a one-token decode step would, so the two agree token for
+    token."""
+    logits, updates = forward_step(
+        params, tokens, caches, pos, cfg, window=window,
+        frontend_feats=frontend_feats, logit_index=logit_index,
+        all_logits=all_logits)
+    return logits, commit(caches, updates)
+
+
+def commit(caches: list, updates: list, keep=None) -> list:
+    """The caches after ``forward_step``'s updates, for the batch rows
+    whose ``keep`` is True (every row when None).  A ``KVWrite`` is
+    written into its cache in place, row by row; any other update (a
+    prefill chunk's cache, a recurrent state) replaces the kept rows."""
+    old, tree = jax.tree.flatten(caches)
+    new = tree.flatten_up_to(updates)
+    writes = [i for i, u in enumerate(new)
+              if isinstance(u, attention.KVWrite)]
+
+    def kept(o, u):
+        if keep is None:
+            return u
+        return jnp.where(keep.reshape((-1,) + (1,) * (o.ndim - 1)), u, o)
+
+    out = [o if i in writes else kept(o, u)
+           for i, (o, u) in enumerate(zip(old, new, strict=True))]
+    if writes:
+        rows = jnp.ones(old[writes[0]].shape[0], bool) \
+            if keep is None else keep
+        written = attention.write_tokens(
+            [old[i] for i in writes], [new[i] for i in writes], rows)
+        for i, c in zip(writes, written, strict=True):
+            out[i] = c
+    return tree.unflatten(out)
+
+
+def forward_step(params: dict, tokens: jax.Array, caches: list, pos,
+                 cfg: ArchConfig, *, window: int | None = None,
+                 frontend_feats=None, logit_index=None,
+                 all_logits: bool = False) -> tuple[jax.Array, list]:
+    """``forward_cached`` without the cache writes: returns (logits,
+    updates).  For a one-token step (``L_new == 1``) an attention layer's
+    update is ``{"k": KVWrite, "v": KVWrite}`` — the token and the row it
+    goes to — so a caller holding a donated pool writes only that token;
+    otherwise (a prefill chunk, a recurrent state) it is the layer's new
+    cache or state, as ``forward_cached`` returns it."""
     cd = jnp.dtype(cfg.compute_dtype)
     window = window if window is not None else cfg.attn_window
     x = flags.constrain(cm.embed(params["embed"], tokens, cd))
     l_new = tokens.shape[1]
     positions = pos + jnp.arange(l_new)
     kinds = cfg.layer_kinds()
-    new_caches = []
+    updates = []
     for layer, kind in enumerate(kinds):
         p = _layer_params(params, cfg, layer)
-        x, _, nc = _apply_block(
+        x, _, update = _apply_block(
             kind, p, x, cfg, positions=positions, window=window,
             impl="chunked", shared=params.get("shared_block"),
             frontend_feats=frontend_feats,
-            cache=caches[layer], cache_pos=pos)
+            cache=caches[layer], cache_pos=pos, stepwise=all_logits)
         x = flags.constrain(x)
-        new_caches.append(nc)
+        updates.append(update)
     if all_logits:
         xs = x
     elif logit_index is None:
         xs = x[:, -1:]
     else:
         xs = jax.lax.dynamic_slice_in_dim(x, logit_index, 1, axis=1)
-    return _logits(params, cfg, xs), new_caches
+    return _logits(params, cfg, xs), updates
 
 
 def init_caches(cfg: ArchConfig, batch: int, max_len: int, *,

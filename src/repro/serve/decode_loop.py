@@ -23,6 +23,12 @@ advances every slot in the pool by up to ``k`` tokens per dispatch.
   fused step (``donate_argnums``), extending the donation pattern of
   ``SlotKVCachePool.write_slot``: XLA aliases the output pool into the
   input buffers, so a decode dispatch allocates no new cache storage.
+* **Token writes in place** — a lane attends its cache row as it stands
+  plus the new token and returns the token (``attention.KVWrite``), not
+  a written row; at the start of the next iteration ``masked_merge``
+  writes one K and one V token per active lane and attention layer into
+  the loop's carried pool, before the lanes read it.  An iteration
+  writes bytes per lane, not the pool.
 * **Device-resident token chain** — the final per-lane tokens come back
   as a device array that feeds the *next* dispatch directly, so the
   host never has to sync a token to keep the loop going; emitted tokens
@@ -43,6 +49,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from ..models import flags, lm
+from ..models.attention import KVWrite
 
 # One compiled fused step serves every depth up to this; the scheduler
 # clamps adaptive depth decisions against it.
@@ -60,10 +67,13 @@ def make_lane_step(cfg: ArchConfig, *, window: int | None = None,
                    kernel_tuner=None) -> Callable:
     """The per-slot decode lane, vmapped over the pool.
 
-    ``lanes(params, caches, toks, poss) -> (next_toks, new_caches)``
-    where every leading dim is ``n_slots``.  Both the legacy per-tick
-    step and the fused loop body call exactly this function, so their
-    per-step numerics cannot diverge.
+    ``lanes(params, caches, toks, poss) -> (next_toks, outs)`` where
+    every leading dim is ``n_slots``.  ``outs`` mirrors the pool: an
+    attention layer's leaves are ``KVWrite``s (the lane's new K/V token
+    ``(H_kv, D)`` and its row), a recurrent layer's the new state.  The
+    lane reads its row and writes nothing; ``masked_merge`` makes the
+    writes.  Both the legacy per-tick step and the fused loop body call
+    exactly this function, so their per-step numerics cannot diverge.
     """
 
     def lane(params, row_caches, tok, pos):
@@ -71,7 +81,7 @@ def make_lane_step(cfg: ArchConfig, *, window: int | None = None,
             lambda x: None if x is None else x[None], row_caches,
             is_leaf=lambda x: x is None)
         with flags.kernel_tuner(kernel_tuner or flags.KERNEL_TUNER):
-            logits, new = lm.forward_cached(
+            logits, new = lm.forward_step(
                 params, tok[None, None], caches, pos, cfg, window=window)
         squeezed = jax.tree.map(
             lambda x: None if x is None else x[0], new,
@@ -83,19 +93,52 @@ def make_lane_step(cfg: ArchConfig, *, window: int | None = None,
 
 @jax.named_scope("merge")
 def masked_merge(caches, new_caches, active):
-    """Keep ``new`` rows only for active lanes: inactive lanes (free
+    """Advance the pool for active lanes only: inactive lanes (free
     slots, mid-prefill slots, lanes past their stop point) must not see
     their KV rows or recurrent states advanced by the garbage token
-    their lane computed.  Its operations carry the scope ``merge``."""
+    their lane computed.
 
-    def keep(old, new):
-        if old is None:
-            return None
-        a = active.reshape((-1,) + (1,) * (old.ndim - 1))
-        return jnp.where(a, new, old)
+    What is merged follows what the lanes returned (``lm.commit``).  A
+    ``KVWrite`` leaf — a token per lane — is written into the pool at
+    its row, for the active lanes alone, in place; an inactive lane's
+    cache is never written.  A full row or state (speculative verify
+    rows, recurrent states) is kept by a ``where`` over the lanes.  Its
+    operations carry the scope ``merge``."""
+    return lm.commit(caches, new_caches, active)
 
-    return jax.tree.map(keep, caches, new_caches,
-                        is_leaf=lambda x: x is None)
+
+def _per_shard(merge: Callable, cache_shardings) -> Callable:
+    """``merge`` run on each device's shard of a mesh-sharded pool: a
+    device writes the tokens of the lanes it holds (slots split over
+    'data', heads or head dims over 'model'; the serve mesh never splits
+    the sequence dim).  Partitioned by GSPMD instead, a write at a
+    traced lane index gathers the pool across the slot axis."""
+    from jax.sharding import PartitionSpec as P
+
+    def dims(sh, ndim):
+        return tuple(sh.spec) + (None,) * (ndim - len(sh.spec))
+
+    first = jax.tree.leaves(cache_shardings)[0]
+    lanes_axis = dims(first, 1)[0]
+
+    def out_spec(sh, out):
+        if isinstance(out, KVWrite):
+            b, h, seq, d = dims(sh, 4)
+            if seq is not None:
+                raise ValueError("per-shard token writes need the pool's "
+                                 f"sequence dim whole; it is split {seq}")
+            return KVWrite(P(b, h, d), P(b))
+        return sh.spec
+
+    def run(caches, outs, active):
+        specs = jax.tree.map(lambda sh: sh.spec, cache_shardings)
+        return jax.shard_map(
+            merge, mesh=first.mesh,
+            in_specs=(specs, jax.tree.map(out_spec, cache_shardings, outs),
+                      P(lanes_axis)),
+            out_specs=specs)(caches, outs, active)
+
+    return run
 
 
 def _replicated_like(shardings):
@@ -156,25 +199,39 @@ def make_fused_decode_step(cfg: ArchConfig, *, window: int | None = None,
                                                       cache_shardings)
         n = toks.shape[0]
         out_buf = jnp.zeros((max_depth, n), jnp.int32)
+        no_writes = jax.tree.map(
+            jnp.zeros_like, jax.eval_shape(lanes, params, caches, toks,
+                                           poss)[1])
+
+        def merge(caches, outs, active):
+            if cache_shardings is None:
+                return masked_merge(caches, outs, active)
+            return _per_shard(masked_merge, cache_shardings)(
+                caches, outs, active)
 
         def body(j, carry):
-            caches, toks, poss, rem, out_buf = carry
+            caches, held, wrote, toks, poss, rem, out_buf = carry
+            # The last iteration's writes land first, so the lanes read
+            # the pool they were written into and XLA updates the carry in
+            # place.  (Made after the lanes, the writes would not be
+            # ordered after the lanes' reads, and XLA would copy the pool.)
+            caches = merge(caches, held, wrote)
             if reshard_to is not None:
                 caches = jax.lax.with_sharding_constraint(caches,
                                                           reshard_to)
             active = rem > 0
-            next_toks, new_caches = lanes(params, caches, toks, poss)
-            caches = masked_merge(caches, new_caches, active)
+            next_toks, outs = lanes(params, caches, toks, poss)
             toks = jnp.where(active, next_toks, toks)
             out_buf = out_buf.at[j].set(toks)
             step = active.astype(poss.dtype)
-            return caches, toks, poss + step, rem - step, out_buf
+            return (caches, outs, active, toks, poss + step, rem - step,
+                    out_buf)
 
         trip = jnp.minimum(jnp.max(steps), max_depth)
-        caches, toks, _, _, out_buf = jax.lax.fori_loop(
-            0, trip, body, (caches, toks, poss,
-                            jnp.minimum(steps, max_depth), out_buf))
-        return caches, out_buf, toks
+        caches, held, wrote, toks, _, _, out_buf = jax.lax.fori_loop(
+            0, trip, body, (caches, no_writes, jnp.zeros(n, bool), toks,
+                            poss, jnp.minimum(steps, max_depth), out_buf))
+        return merge(caches, held, wrote), out_buf, toks
 
     return jax.jit(fused, donate_argnums=(1,))
 
@@ -259,10 +316,11 @@ def make_spec_lane_step(cfg: ArchConfig, *, depth: int,
     ``seqs`` is ``(n_slots, depth)`` — each lane's carry token followed
     by its ``depth - 1`` drafts — and ``verified`` is ``(n_slots,
     depth)``, the greedy argmax after every fed position.  One forward
-    verifies all ``depth`` positions; it is the same
-    ``lm.forward_cached`` the non-speculative lane runs, just fed a
-    chunk, so position ``j``'s logits are byte-identical to what ``j``
-    sequential steps over the same tokens would produce."""
+    verifies all ``depth`` positions; it is the model the
+    non-speculative lane runs, just fed a chunk (which it writes before
+    attending it), and each position attends the same cache rows plus
+    its own token, so position ``j``'s logits are byte-identical to what
+    ``j`` sequential steps over the same tokens would produce."""
     _check_spec_arch(cfg, window)
 
     def lane(params, row_caches, seq, pos):
@@ -545,15 +603,15 @@ def make_paged_lane_step(cfg: ArchConfig, *, page_size: int, max_len: int,
     int32.  Each lane gathers its pages into the *same contiguous
     ``(H_kv, max_len, D)`` view* the slot pool hands
     ``make_lane_step``'s lane, then runs the identical
-    ``lm.forward_cached`` — byte-for-byte the contiguous computation,
+    ``lm.forward_step`` — byte-for-byte the contiguous computation,
     because every position past the lane's ``kv_len`` is masked to
     exactly zero weight regardless of which garbage the unmapped
     (scratch-page) gather rows carry.
 
-    ``lane_outs`` is per-layer: the newly-written KV token ``(H_kv, D)``
-    for attention layers (sliced back out of the lane's private view —
-    the caller scatters it into the shared page store *outside* the
-    vmap), the full new state for recurrent layers.
+    ``lane_outs`` is per-layer: the new KV token ``(H_kv, D)`` for
+    attention layers (``KVWrite.token``; the caller scatters it into the
+    shared page store *outside* the vmap), the full new state for
+    recurrent layers.
     """
     kinds = tuple(cfg.layer_kinds())
     ps = int(page_size)
@@ -574,7 +632,7 @@ def make_paged_lane_step(cfg: ArchConfig, *, page_size: int, max_len: int,
         row = [view(kind, c) for kind, c in zip(kinds, caches,
                                                 strict=True)]
         with flags.kernel_tuner(kernel_tuner or flags.KERNEL_TUNER):
-            logits, new = lm.forward_cached(
+            logits, new = lm.forward_step(
                 params, tok[None, None], row, pos, cfg, window=None)
 
         def out(kind, c):
@@ -582,8 +640,8 @@ def make_paged_lane_step(cfg: ArchConfig, *, page_size: int, max_len: int,
                 return None
             if kind in _ATTN_KINDS:
                 return jax.tree.map(
-                    lambda x: jax.lax.dynamic_slice_in_dim(
-                        x[0], pos, 1, axis=1)[:, 0, :], c)
+                    lambda w: w.token[0], c,
+                    is_leaf=lambda x: isinstance(x, KVWrite))
             return jax.tree.map(lambda x: x[0], c)
 
         outs = [out(kind, c) for kind, c in zip(kinds, new, strict=True)]
@@ -627,18 +685,11 @@ def make_paged_decode_step(cfg: ArchConfig, *, page_size: int,
         n = toks.shape[0]
         out_buf = jnp.zeros((max_depth, n), jnp.int32)
         lane_ix = jnp.arange(n)
+        no_writes = jax.tree.map(
+            jnp.zeros_like, jax.eval_shape(lanes, params, page_tables,
+                                           caches, toks, poss)[1])
 
-        def body(j, carry):
-            caches, toks, poss, rem, out_buf = carry
-            if reshard_to is not None:
-                caches = jax.lax.with_sharding_constraint(caches,
-                                                          reshard_to)
-            active = rem > 0
-            next_toks, outs = lanes(params, page_tables, caches, toks,
-                                    poss)
-            pages = page_tables[lane_ix, poss // ps]
-            flat_ix = jnp.where(active, pages * ps + poss % ps, 0)
-
+        def write(caches, outs, flat_ix, active):
             def merge(kind, c, o):
                 if c is None:
                     return None
@@ -648,17 +699,34 @@ def make_paged_decode_step(cfg: ArchConfig, *, page_size: int,
                         c, o)
                 return masked_merge(c, o, active)
 
-            caches = [merge(kind, c, o) for kind, c, o in
-                      zip(kinds, caches, outs, strict=True)]
+            return [merge(kind, c, o) for kind, c, o in
+                    zip(kinds, caches, outs, strict=True)]
+
+        def body(j, carry):
+            caches, held, held_ix, wrote, toks, poss, rem, out_buf = carry
+            # As in the contiguous step: the last iteration's tokens land
+            # first, so the lanes read what was just written in place.
+            caches = write(caches, held, held_ix, wrote)
+            if reshard_to is not None:
+                caches = jax.lax.with_sharding_constraint(caches,
+                                                          reshard_to)
+            active = rem > 0
+            next_toks, outs = lanes(params, page_tables, caches, toks,
+                                    poss)
+            pages = page_tables[lane_ix, poss // ps]
+            flat_ix = jnp.where(active, pages * ps + poss % ps, 0)
             toks = jnp.where(active, next_toks, toks)
             out_buf = out_buf.at[j].set(toks)
             step = active.astype(poss.dtype)
-            return caches, toks, poss + step, rem - step, out_buf
+            return (caches, outs, flat_ix, active, toks, poss + step,
+                    rem - step, out_buf)
 
         trip = jnp.minimum(jnp.max(steps), max_depth)
-        caches, toks, _, _, out_buf = jax.lax.fori_loop(
-            0, trip, body, (caches, toks, poss,
-                            jnp.minimum(steps, max_depth), out_buf))
-        return caches, out_buf, toks
+        caches, held, held_ix, wrote, toks, _, _, out_buf = \
+            jax.lax.fori_loop(0, trip, body, (
+                caches, no_writes, jnp.zeros(n, jnp.int32),
+                jnp.zeros(n, bool), toks, poss, jnp.minimum(steps, max_depth),
+                out_buf))
+        return write(caches, held, held_ix, wrote), out_buf, toks
 
     return jax.jit(fused, donate_argnums=(1,))

@@ -304,7 +304,7 @@ class PagedKVCachePool:
     gather of its table's pages (``decode_loop.make_paged_decode_step``
     and ``read_slot`` build that contiguous view), so the model code
     underneath is byte-identical to the contiguous pool: same
-    ``lm.forward_cached``, same masked attention, garbage past a lane's
+    ``lm.forward_step``, same masked attention, garbage past a lane's
     position masked to exactly zero either way.  Recurrent layer state
     (SSM/xLSTM) has no sequence axis to page — it stays slot-major,
     exactly as in ``SlotKVCachePool``.

@@ -133,6 +133,51 @@ def test_audit_report_serialises():
     assert "hlo-audit" in hlo_audit.format_report(rep)
 
 
+def test_audit_counts_pool_shaped_selects_and_copies():
+    """The mechanism counter: loop-body selects and copies whose result
+    has a pool leaf's shape, TPU tiled layouts included, each op once
+    however many loop closures share it."""
+    bodies = hlo_audit.loop_body_texts(LOOP_HLO)
+    assert hlo_audit.pool_shaped_ops(bodies, {(4, 64)}) == \
+        {"select": 0, "copy": 1}
+    assert hlo_audit.pool_shaped_ops(bodies, {(4, 2, 32, 16)}) == \
+        {"select": 0, "copy": 0}      # the all-gather is not counted
+    line = ("  %select.1 = bf16[24,8,2048,128]{3,2,1,0:T(8,128)(2,1)} "
+            "select(%p, %a, %b)\n")
+    assert hlo_audit.pool_shaped_ops({"outer": line, "inner": line},
+                                     {(24, 8, 2048, 128)}) == \
+        {"select": 1, "copy": 0}
+    rep = hlo_audit.audit_hlo(LOOP_HLO,
+                              hlo_audit.AuditPolicy(model_parallel=2),
+                              pool_shapes={(4, 64)})
+    assert (rep.pool_selects, rep.pool_copies) == (0, 1)
+    assert rep.to_dict()["pool_copies"] == 1
+    assert "pool-shaped selects=0 copies=1" in hlo_audit.format_report(rep)
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "h2o-danube-1.8b"])
+def test_fused_step_writes_pool_in_place(arch):
+    """The contiguous fused decode step writes each active lane's K/V
+    token into the carried pool: its loop bodies hold no select and no
+    copy with a pool leaf's shape (a whole-pool ``where`` merge, a
+    copied carry).  h2o-danube's reduced window (16 rows of a 64-row
+    pool) makes its pool a ring."""
+    from repro.models import init_caches, init_params
+    from repro.serve.decode_loop import make_fused_decode_step
+
+    cfg = get_config(arch).reduced()
+    caches = init_caches(cfg, 4, 64, window=cfg.attn_window)
+    step = make_fused_decode_step(cfg, window=cfg.attn_window, max_depth=4)
+    z = jnp.zeros(4, jnp.int32)
+    text = step.lower(init_params(jax.random.PRNGKey(0), cfg), caches,
+                      z, z, z).compile().as_text()
+    bodies = hlo_audit.loop_body_texts(text)
+    assert bodies
+    shapes = {tuple(x.shape) for x in jax.tree.leaves(caches)}
+    assert hlo_audit.pool_shaped_ops(bodies, shapes) == \
+        {"select": 0, "copy": 0}
+
+
 AUDIT_GATE = """
 from repro.analysis import hlo_audit
 
@@ -155,3 +200,5 @@ def test_audit_gate_on_emulated_mesh(subproc):
     assert "AUDIT_GATE_OK" in r.stdout
     assert "VIOLATION" in r.stdout          # the injected run printed it
     assert "all-gather" in r.stdout
+    # the live step's tokens land in place on the sharded pool too
+    assert "pool-shaped selects=0 copies=0" in r.stdout

@@ -6,7 +6,9 @@ described, not attached, and refuses what the chip would refuse (tiles
 that do not divide the layout, scoped VMEM past the kernel limit).  Each
 case compiles at a block the planners or the autotuner can pick — the
 analytic prior and the largest VMEM-budgeted candidate — and checks that
-the program holds the Mosaic kernel (``tpu_custom_call``).
+the program holds the Mosaic kernel (``tpu_custom_call``).  The fused
+decode step is compiled too, for what the TPU's layouts do to its loop
+body.
 """
 import os
 
@@ -15,6 +17,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.analysis import hlo_audit
+from repro.configs import get_config
 from repro.kernels import autotune, tuning
 from repro.kernels.adjacent_difference import adjacent_difference_pallas
 from repro.kernels.artificial_work import artificial_work_pallas
@@ -22,6 +26,8 @@ from repro.kernels.flash_attention import (flash_attention_pallas,
                                            paged_flash_attention_pallas)
 from repro.kernels.reduce_scan import inclusive_scan_pallas, reduce_sum_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
+from repro.models import init_caches, init_params
+from repro.serve.decode_loop import make_fused_decode_step
 
 D_MODEL, HEAD_DIM, HQ, HKV = 1024, 128, 16, 8
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -124,3 +130,28 @@ def test_one_d_kernels(one_chip, kernel, which):
     block = {"prior": cands[0], "largest": max(cands),
              "smallest": min(cands)}[which]
     _compiles(make(block), one_chip, ((n // block * block,), F32))
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_fused_decode_step_writes_pool_in_place(one_chip, dtype):
+    """On the chip's layouts too, the fused decode step's loop bodies
+    hold no select and no copy with a pool leaf's shape: a token write
+    narrower than a layout tile makes the TPU compiler lay the cache out
+    by heads and copy the whole pool at every step."""
+    cfg = get_config("qwen3-0.6b").reduced()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    caches = jax.eval_shape(lambda: init_caches(cfg, 4, 256, dtype=dtype))
+    lanes = jax.ShapeDtypeStruct((4,), I32, sharding=one_chip)
+    text = make_fused_decode_step(cfg, max_depth=4).lower(
+        on_chip(params), on_chip(caches), lanes, lanes, lanes
+    ).compile().as_text()
+    bodies = hlo_audit.loop_body_texts(text)
+    assert bodies
+    shapes = {tuple(x.shape) for x in jax.tree.leaves(caches)}
+    assert hlo_audit.pool_shaped_ops(bodies, shapes) == \
+        {"select": 0, "copy": 0}

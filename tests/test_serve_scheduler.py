@@ -464,6 +464,56 @@ def test_fused_tickrecords_and_positions(setup):
     assert sched.decode_tokens == 6
 
 
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(f"u{x.dtype.itemsize}")
+
+
+@pytest.mark.parametrize("depth", [1, 4])
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "h2o-danube-1.8b",
+                                  "zamba2-1.2b"])
+def test_fused_dispatch_writes_only_active_rows(arch, depth):
+    """One fused dispatch leaves an inactive lane's cache bit-identical
+    (a free slot, a slot mid-prefill, and the positions a lane did not
+    reach because its budget ran out mid-loop), and changes an active
+    lane's K/V rows exactly at the positions it wrote.  h2o-danube's
+    reduced window makes the pool a 16-row ring, which the deep lane
+    wraps; zamba2's mamba2 states keep the row merge, so only active
+    lanes' states move."""
+    from repro.models import init_caches
+    from repro.serve.decode_loop import make_fused_decode_step
+
+    cfg = get_config(arch).reduced()
+    window = cfg.attn_window
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.RandomState(7)
+    caches = jax.tree.map(
+        lambda x: jnp.asarray(rng.standard_normal(x.shape), x.dtype),
+        init_caches(cfg, 4, 64, window=window))
+    before = jax.tree.map(lambda x: np.array(_bits(x)), caches)
+    # lane 0 decodes the whole dispatch; lane 1 has one token of budget;
+    # lane 2 is a free slot, lane 3 a slot mid-prefill
+    poss = np.array([30, 20, 0, 10], np.int32)
+    steps = np.array([depth, 1, 0, 0], np.int32)
+    step = make_fused_decode_step(cfg, window=window, max_depth=depth)
+    after, _, _ = step(params, caches, jnp.asarray([3, 5, 7, 9], jnp.int32),
+                       jnp.asarray(poss), jnp.asarray(steps))
+    for kind, old, new in zip(cfg.layer_kinds(), before, after,
+                              strict=True):
+        for key in old:
+            got = _bits(new[key])
+            for lane in range(4):
+                same = got[lane] == old[key][lane]
+                if kind not in ("attn", "shared_attn"):
+                    assert same.all() == (steps[lane] == 0), (kind, lane)
+                    continue
+                s_cache = same.shape[1]
+                wrote = {int(poss[lane] + j) % s_cache
+                         for j in range(steps[lane])}
+                moved = set(np.nonzero(~same.all(axis=(0, 2)))[0].tolist())
+                assert moved == wrote, (kind, key, lane)
+
+
 def test_scheduler_on_host_parallel_executor(setup):
     """Prefill chunks may run on pool threads; cache writes stay on the
     scheduler thread — results must match the sequential schedule."""
