@@ -135,28 +135,40 @@ def log_window(run, counter) -> None:
         + "".join(f"; {c.error}" for c in due if c.error)[:300])
 
 
+def scheduler(cell, seed: int):
+    """The program's scheduler for ``cell``, serving weights made from
+    ``seed`` by its family."""
+    fam = cell.family
+    cfg = fam.arch_config(cell.model, cell.config_name)
+    params = fam.program_params(
+        weights.make(fam, cell.model, seed, cfg.param_dtype),
+        fam.dims(cell.model))
+    return serving.build_scheduler(cfg, params, cell.model)
+
+
+def clients_and_warm_prompts(cell, seed: int, seconds: float):
+    """The run's clients, in sending order, and its warm-up prompts."""
+    mix, vocab = cell.traffic, cell.family.dims(cell.model)["vocab"]
+    reqs = traffic.plan(mix, seed, seconds)
+    clients = [serving.Client(r, p) for r, p in zip(
+        reqs, traffic.prompts(reqs, vocab, seed), strict=True)]
+    warm = traffic.prompts(warm_set(reqs), vocab, seed, warm=True)
+    return clients, warm
+
+
 def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
              *, control: bool = False, peaks: dict | None = None) -> dict:
     """Set up, measure and check one run; returns the result record."""
-    cfg = spec.arch_config(cell.model, cell.config_name)
-    dm = weights.dims(cell.model)
     mix = cell.traffic
     counter = serving.CompileCounter()
-    sched = serving.build_scheduler(
-        cfg, weights.program_params(
-            weights.make(cell.model, seed, cfg.param_dtype), dm),
-        cell.model)
+    sched = scheduler(cell, seed)
     log(f"set-up: weights and pool made at "
         f"{time.perf_counter() - t_start:.1f}s")
     sched.warmup()
     log(f"set-up: scheduler warm-up done at "
         f"{time.perf_counter() - t_start:.1f}s")
-    reqs = traffic.plan(mix, seed, seconds)
-    clients = [serving.Client(r, p) for r, p in zip(
-        reqs, traffic.prompts(reqs, dm["vocab"], seed), strict=True)]
-    warm = warm_set(reqs)
-    warm_prompts = traffic.prompts(warm, dm["vocab"], seed, warm=True)
-    log(f"set-up: {len(warm)} warm-up prompts, "
+    clients, warm_prompts = clients_and_warm_prompts(cell, seed, seconds)
+    log(f"set-up: {len(warm_prompts)} warm-up prompts, "
         f"{counter.compiles} compiles so far ({counter.compile_s:.1f}s), "
         f"{counter.cache_hits} persistent-cache hits")
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
@@ -174,7 +186,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
         shutil.rmtree(trace_dir, ignore_errors=True)
         dev["busy_s"] = summary.busy_s
         dev["window_s"] = summary.window_s
-    run = reading.Run(cell=cell, dims=dm, dtype=cell.model["torch_dtype"],
+    run = reading.Run(cell=cell, dims=cell.family.dims(cell.model),
+                      dtype=cell.model["torch_dtype"],
                       n_slots=sched.pool.n_slots, setup_s=setup_s, window=win,
                       trace=summary, peaks=peaks or {})
     log_window(run, counter)
@@ -184,7 +197,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
 
     picks = correct.sample(win.clients, seed)
     t_ref = time.perf_counter()
-    cmp = correct.compare(cell.model, seed, picks, max_len, control=control)
+    cmp = correct.compare(cell.family, cell.model, seed, picks, max_len,
+                          control=control)
     log(f"reference: {cmp['requests']} requests, {cmp['tokens']} served "
         f"tokens checked in {time.perf_counter() - t_ref:.1f}s; the "
         f"program's widest gap {cmp['logit_gap']!r}")

@@ -2,8 +2,9 @@
 
 Once the window has closed and the program is gone, a sample of the
 requests the window finished, drawn from the seed and always holding the
-longest, is run through the plain float32 reference (``bench/reference``)
-over prompt plus served tokens.  For each served token the reference
+longest, is run through the plain float32 reference of the
+configuration's family (``bench/reference/<family.REFERENCE>.py``) over
+prompt plus served tokens.  For each served token the reference
 gives the gap by which that token's logit lies below its best; the
 number compared is the widest gap of the sample.  Greedy decoding serves
 the token the program scores highest, so a sound program's gaps are its
@@ -18,6 +19,8 @@ from __future__ import annotations
 import math
 
 import numpy as np
+
+import families
 
 from . import traffic, weights
 
@@ -44,15 +47,15 @@ def sample(clients: list, seed: int) -> list:
     return picks
 
 
-def compare(model: dict, seed: int, picks: list, max_len: int, *,
+def compare(fam, model: dict, seed: int, picks: list, max_len: int, *,
             control: bool = False) -> dict:
     """Widest reference gap of the served tokens (and of the control's
-    first choices with ``control``) over ``picks``."""
+    first choices with ``control``) over ``picks``; ``fam`` is the
+    configuration's family."""
     import jax
 
-    from reference import model as ref
-
-    w = weights.make(model, seed, "float32")
+    ref = families.reference(fam)
+    w = weights.make(fam, model, seed, "float32")
     cfg = ref.config(model)
     length = -(-max_len // ref.HEAD_BLOCK) * ref.HEAD_BLOCK
     worst, checked = 0.0, 0

@@ -10,13 +10,11 @@ import dataclasses
 
 import numpy as np
 
-from . import shapes
-
 
 @dataclasses.dataclass
 class Run:
     cell: object                  # spec.Cell
-    dims: dict                    # weights.dims of the configuration
+    dims: dict                    # cell.family.dims of the configuration
     dtype: str                    # stated compute dtype
     n_slots: int
     setup_s: float
@@ -106,13 +104,12 @@ def decode_totals(run: Run) -> tuple[int, int]:
 def model_flops(run: Run) -> int:
     """Operations the model needs for every prompt token prefilled and
     every token decoded in the counted span."""
-    total = 0
+    fam, total = run.cell.family, 0
     for r in run.window.requests:
-        total += shapes.prefill_flops(run.dims, r["prefilled"] - r["computed"],
-                                      r["prefilled"])
+        total += fam.prefill_flops(run.dims, r["prefilled"] - r["computed"],
+                                   r["prefilled"])
     tokens, kv = decode_totals(run)
-    return total + 2 * shapes.matmul_params(run.dims) * tokens \
-        + shapes.attn_flops(run.dims, 1) * kv
+    return total + fam.decode_work(run.dims, run.dtype, 0, kv, tokens)[0]
 
 
 def decode_iter_ms(run: Run) -> float | None:
@@ -127,17 +124,18 @@ def decode_iter_ms(run: Run) -> float | None:
 
 def decode_roofline(run: Run) -> float | None:
     """The fused decode program's share of its roofline: the least time
-    the chip needs for the work the iterations require (``shapes``:
-    weights once per iteration at the stated dtype, live KV only, flops of
-    live lanes), over the program's measured device time.  Decode at
-    these batch sizes is bound by bytes in every iteration, so the bound
-    of the sums is the sum of the bounds."""
+    the chip needs for the work the iterations require (the family's
+    ``decode_work``: weights once per iteration at the stated dtype, live
+    state only, flops of live lanes), over the program's measured device
+    time.  Decode at these batch sizes is bound by bytes in every
+    iteration, so the bound of the sums is the sum of the bounds."""
     dev = program_seconds(run, "fused")
     iters = run.window.counters["decode_loop_iters"]
     if not dev or not iters:
         return None
     tokens, kv = decode_totals(run)
-    flops, byts = shapes.decode_work(run.dims, run.dtype, iters, kv, tokens)
+    flops, byts = run.cell.family.decode_work(run.dims, run.dtype, iters,
+                                              kv, tokens)
     least = max(flops / run.peaks["bf16_flops_per_s"],
                 byts / run.peaks["hbm_bytes_per_s"])
     return 100.0 * least / dev

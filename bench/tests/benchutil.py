@@ -43,5 +43,5 @@ def tiny_cell(name: str, **mix_over) -> spec.Cell:
     return spec.Cell(name=name, config_name=cell.config_name,
                      traffic_name=cell.traffic_name, chips=1,
                      model=tiny_model(cell.config_name),
-                     traffic=mix, limits=cell.limits,
+                     family=cell.family, traffic=mix, limits=cell.limits,
                      end_to_end=cell.end_to_end, per_layer=cell.per_layer)

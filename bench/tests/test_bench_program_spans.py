@@ -8,29 +8,21 @@ import math
 import pytest
 from benchutil import tiny_cell
 
-from harness import (cli, program_spans, reading, serving, spec, traffic,
-                     weights)
+from harness import cli, program_spans, reading, serving, spec
 
 SEED, SECONDS = 2**31 + 77, 4.0
 
 
 def window_run(name: str) -> reading.Run:
     cell = tiny_cell(name)
-    cfg = spec.arch_config(cell.model, cell.config_name)
-    dm = weights.dims(cell.model)
-    sched = serving.build_scheduler(
-        cfg, weights.program_params(weights.make(cell.model, SEED), dm),
-        cell.model)
+    sched = cli.scheduler(cell, SEED)
     sched.warmup()
-    reqs = traffic.plan(cell.traffic, SEED, SECONDS)
-    clients = [serving.Client(r, p) for r, p in zip(
-        reqs, traffic.prompts(reqs, dm["vocab"], SEED), strict=True)]
-    warm = traffic.prompts(cli.warm_set(reqs), dm["vocab"], SEED, warm=True)
+    clients, warm = cli.clients_and_warm_prompts(cell, SEED, SECONDS)
     win = serving.run_window(sched, cell.traffic, clients, warm, SECONDS,
                              serving.CompileCounter())
-    return reading.Run(cell=cell, dims=dm, dtype="bfloat16",
-                       n_slots=sched.pool.n_slots, setup_s=0.0, window=win,
-                       trace=None, peaks={})
+    return reading.Run(cell=cell, dims=cell.family.dims(cell.model),
+                       dtype="bfloat16", n_slots=sched.pool.n_slots,
+                       setup_s=0.0, window=win, trace=None, peaks={})
 
 
 @pytest.fixture(scope="module")

@@ -5,8 +5,11 @@ import pytest
 
 from benchutil import BENCH
 
-from harness import reading, shapes, spec, weights
+import families
+from harness import reading, spec
 from harness.xtrace import Summary
+
+DENSE = families.named("dense")
 
 
 def client(due, times=(), status="completed"):
@@ -18,7 +21,8 @@ def make_run(clients, requests=(), counters=None, trace=None):
                                    requests=list(requests),
                                    counters=counters or {})
     model = spec.load_json(BENCH / "configs" / "qwen3-0.6b.json")
-    return reading.Run(cell=None, dims=weights.dims(model), dtype="bfloat16",
+    return reading.Run(cell=types.SimpleNamespace(family=DENSE),
+                       dims=DENSE.dims(model), dtype="bfloat16",
                        n_slots=4, setup_s=1.0, window=window, trace=trace,
                        peaks={"bf16_flops_per_s": 197e12,
                               "hbm_bytes_per_s": 819e9})
@@ -64,16 +68,16 @@ def test_decode_work_counts_live_context():
     # tokens 3..5 of the third 23, 24, 25
     assert reading.decode_totals(run) == (6, 36 + 72)
     flops = reading.model_flops(run)
-    assert flops == shapes.prefill_flops(run.dims, 0, 10) \
-        + shapes.prefill_flops(run.dims, 1024, 1100) \
-        + 6 * 2 * shapes.matmul_params(run.dims) \
-        + shapes.attn_flops(run.dims, 108)
+    assert flops == DENSE.prefill_flops(run.dims, 0, 10) \
+        + DENSE.prefill_flops(run.dims, 1024, 1100) \
+        + 6 * 2 * DENSE.matmul_params(run.dims) \
+        + DENSE.attn_flops(run.dims, 108)
 
 
 def test_roofline_share_stays_under_the_peak():
     reqs = [{"prompt_len": 512, "generated0": 0, "generated": 65,
              "computed": 512, "prefilled": 512}]
-    byts = shapes.weight_bytes(make_run([]).dims, "bfloat16") * 64
+    byts = DENSE.weight_bytes(make_run([]).dims, "bfloat16") * 64
     least = byts / 819e9
     trace = Summary(window_s=1.0, busy_s=0.5, n_devices=1,
                     programs={"fused": 2 * least}, program_runs={"fused": 1},

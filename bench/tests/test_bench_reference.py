@@ -12,7 +12,8 @@ import pytest
 
 from benchutil import tiny_model
 
-from harness import spec, weights
+import families
+from harness import weights
 from reference import model as ref
 
 CONFIGS = ["qwen3-0.6b", "h2o-danube-1.8b"]
@@ -31,12 +32,13 @@ def test_reference_matches_program_forward(config):
     from repro.models import lm
 
     model = tiny_model(config)
-    cfg = dataclasses.replace(spec.arch_config(model, config),
+    fam = families.load(model)
+    cfg = dataclasses.replace(fam.arch_config(model, config),
                               compute_dtype="float32")
-    w = weights.make(model, seed=3)
+    w = weights.make(fam, model, seed=3)
     tokens = np.random.default_rng(0).integers(0, 512, 48).astype(np.int32)
     with jax.default_matmul_precision("highest"):
-        want, _ = lm.forward(weights.program_params(w, weights.dims(model)),
+        want, _ = lm.forward(fam.program_params(w, fam.dims(model)),
                              {"tokens": jnp.asarray(tokens)[None]}, cfg)
     got = _ref_logits(w, model, jnp.asarray(tokens))
     want = np.asarray(want[0])
@@ -49,7 +51,7 @@ def test_gaps_read_the_served_tokens(config):
     """``gaps`` is zero where the target is the reference's argmax and
     the logit difference elsewhere; the fp8 control departs from it."""
     model = tiny_model(config)
-    w = weights.make(model, seed=4)
+    w = weights.make(families.load(model), model, seed=4)
     tokens = jnp.asarray(np.random.default_rng(1).integers(0, 512, 256),
                          jnp.int32)
     logits = _ref_logits(w, model, tokens)

@@ -11,7 +11,8 @@ import pytest
 
 from benchutil import BENCH, ROOT
 
-from harness import shapes, spec, weights
+import families
+from harness import spec, weights
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -61,11 +62,12 @@ def test_program_params_match_the_program_layout(config):
     from repro.models import lm
 
     model = spec.load_json(BENCH / "configs" / f"{config}.json")
-    cfg = spec.arch_config(model, config)
-    dm = weights.dims(model)
+    fam = families.load(model)
+    cfg = fam.arch_config(model, config)
+    dm = fam.dims(model)
     want = jax.eval_shape(lambda: lm.init_params(jax.random.key(0), cfg))
-    got = jax.eval_shape(lambda: weights.program_params(
-        weights._maker(tuple(sorted(dm.items())), "float32")(
+    got = jax.eval_shape(lambda: fam.program_params(
+        weights._maker(fam, tuple(sorted(dm.items())), "float32")(
             jax.random.key(0)), dm))
     assert jax.tree.structure(got) == jax.tree.structure(want)
     assert [x.shape for x in jax.tree.leaves(got)] == \
@@ -73,18 +75,20 @@ def test_program_params_match_the_program_layout(config):
     # the shape arithmetic counts what the program's own count does: every
     # weight a token multiplies by, the embedding only as the (tied) head
     untied_embed = 0 if dm["tied"] else dm["vocab"] * dm["d"]
-    assert shapes.matmul_params(dm) == cfg.param_count() - untied_embed
+    assert fam.matmul_params(dm) == cfg.param_count() - untied_embed
+
+
+def _arch(config):
+    model = spec.load_json(BENCH / "configs" / f"{config}.json")
+    return families.load(model).arch_config(model, config)
 
 
 def test_configs_state_their_published_shapes():
-    q = spec.arch_config(spec.load_json(BENCH / "configs/qwen3-0.6b.json"),
-                         "qwen3-0.6b")
+    q = _arch("qwen3-0.6b")
     assert (q.n_layers, q.d_model, q.n_heads, q.n_kv_heads, q.head_dim_,
             q.d_ff, q.vocab_size) == (28, 1024, 16, 8, 128, 3072, 151936)
     assert q.qk_norm and q.tie_embeddings and q.rope_theta == 1e6
-    d = spec.arch_config(
-        spec.load_json(BENCH / "configs/h2o-danube-1.8b.json"),
-        "h2o-danube-1.8b")
+    d = _arch("h2o-danube-1.8b")
     assert (d.n_layers, d.d_model, d.n_heads, d.n_kv_heads, d.head_dim_,
             d.d_ff, d.vocab_size) == (24, 2560, 32, 8, 80, 6912, 32000)
     assert not d.qk_norm and not d.tie_embeddings and d.attn_window is None

@@ -6,7 +6,7 @@ import tempfile
 
 from benchutil import tiny_cell
 
-from harness import cli, reading, serving, spec, traffic, weights
+from harness import cli, reading, serving
 
 SEED, SECONDS = 2**31 + 99, 5.0
 
@@ -14,20 +14,14 @@ SEED, SECONDS = 2**31 + 99, 5.0
 def test_traced_span_counts_what_it_dispatched(monkeypatch):
     monkeypatch.setattr(serving, "TRACE_SECONDS", 2.0)
     cell = tiny_cell("qwen3-chat")
-    cfg = spec.arch_config(cell.model, cell.config_name)
-    dm = weights.dims(cell.model)
-    sched = serving.build_scheduler(
-        cfg, weights.program_params(weights.make(cell.model, SEED), dm),
-        cell.model)
+    sched = cli.scheduler(cell, SEED)
     sched.warmup()
-    reqs = traffic.plan(cell.traffic, SEED, SECONDS)
-    clients = [serving.Client(r, p) for r, p in zip(
-        reqs, traffic.prompts(reqs, dm["vocab"], SEED), strict=True)]
-    warm = traffic.prompts(cli.warm_set(reqs), dm["vocab"], SEED, warm=True)
+    clients, warm = cli.clients_and_warm_prompts(cell, SEED, SECONDS)
     with tempfile.TemporaryDirectory() as trace_dir:
         win = serving.run_window(sched, cell.traffic, clients, warm, SECONDS,
                                  serving.CompileCounter(), trace_dir=trace_dir)
-    run = reading.Run(cell=cell, dims=dm, dtype="bfloat16", n_slots=4,
+    run = reading.Run(cell=cell, dims=cell.family.dims(cell.model),
+                      dtype="bfloat16", n_slots=4,
                       setup_s=0.0, window=win, trace=None, peaks={})
     tokens, _ = reading.decode_totals(run)
     assert 1.5 < win.span_s < 3.5
