@@ -14,6 +14,7 @@ _ARCH_MODULES = {
     "qwen1.5-32b": "qwen1_5_32b",
     "llama-3.2-vision-11b": "llama_3_2_vision_11b",
     "musicgen-medium": "musicgen_medium",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCH_NAMES = tuple(_ARCH_MODULES)

@@ -27,6 +27,16 @@ class ArchConfig:
     qkv_bias: bool = False
     attn_window: int | None = None         # SWA window (None = full)
     rope_theta: float = 10000.0
+    rope: bool = True                      # False: no position embedding
+    attn_scale: float | None = None        # softmax scale; 1/sqrt(head_dim)
+
+    # muP multipliers (Granite 4.0): the embedding is multiplied by
+    # ``embed_multiplier``, each residual addition's branch by
+    # ``residual_multiplier``, and the logits divided by
+    # ``logits_divisor``.  The defaults leave the model as it was.
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_divisor: float = 1.0
 
     # MoE
     n_experts: int = 0
@@ -34,7 +44,8 @@ class ArchConfig:
     capacity_factor: float = 1.25
 
     # layer pattern, cycled over the depth. kinds:
-    #   attn (self-attn + ffn/moe), mamba2, slstm, mlstm,
+    #   attn (self-attn + ffn/moe), mamba2 (+ ffn with mamba_ffn),
+    #   slstm, mlstm,
     #   shared_attn (zamba2 shared transformer block),
     #   cross_attn (vlm image cross-attention + ffn)
     block_pattern: tuple[str, ...] = ("attn",)
@@ -44,6 +55,7 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     conv_width: int = 4
+    mamba_ffn: bool = False      # a mamba2 layer is followed by ln2 + FFN
 
     # modality frontends (stubs: precomputed embeddings via input_specs)
     frontend: str | None = None            # vision | audio | None
@@ -104,7 +116,8 @@ class ArchConfig:
         in_proj = d * (2 * di + 2 * n + h)   # z, x, B, C, dt
         conv = (di + 2 * n) * self.conv_width
         out = di * d
-        return in_proj + conv + out + 2 * h  # + A, D per head
+        # + conv bias, A, Δ bias and D per head, the gated norm's gain
+        return in_proj + conv + (di + 2 * n) + out + 3 * h + di
 
     def _mlstm_params(self) -> int:
         d, di = self.d_model, self.d_inner
@@ -123,7 +136,8 @@ class ArchConfig:
         if kind in ("shared_attn", "cross_attn"):
             return self._attn_params() + self._ffn_params()
         if kind == "mamba2":
-            return self._mamba_params()
+            ff = self._ffn_params() if self.mamba_ffn else 0
+            return self._mamba_params() + ff
         if kind == "mlstm":
             return self._mlstm_params()
         if kind == "slstm":

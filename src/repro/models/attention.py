@@ -179,12 +179,15 @@ def self_attention(p: dict, x: jax.Array, cfg: ArchConfig, *,
     if cfg.qk_norm:
         q = cm.rmsnorm(p["q_norm"], q, cfg.norm_eps)
         k = cm.rmsnorm(p["k_norm"], k, cfg.norm_eps)
-    cos, sin = cm.rope_angles(positions, hd, cfg.rope_theta)
-    q = cm.apply_rope(q, cos, sin)
-    k = cm.apply_rope(k, cos, sin)
+    if cfg.rope:
+        cos, sin = cm.rope_angles(positions, hd, cfg.rope_theta)
+        q = cm.apply_rope(q, cos, sin)
+        k = cm.apply_rope(k, cos, sin)
+    scale = cfg.attn_scale
 
     if cache is None:
-        out = sdpa(q, k, v, impl=impl, causal=True, window=window)
+        out = sdpa(q, k, v, impl=impl, causal=True, window=window,
+                   scale=scale)
         new_cache = None
     else:
         s_cache = cache["k"].shape[2]
@@ -195,7 +198,7 @@ def self_attention(p: dict, x: jax.Array, cfg: ArchConfig, *,
             # Decode: attend the cache as it stands plus the new token,
             # and hand the write back instead of a written cache.
             out = _step_attention(
-                q, cache["k"], cache["v"], kt, vt, cache_pos,
+                q, cache["k"], cache["v"], kt, vt, cache_pos, scale=scale,
                 replaced=slot if window is not None else None)
             at = jnp.full((q.shape[0],), slot, jnp.int32)
             new_cache = {"k": KVWrite(kt[:, :, 0], at),
@@ -207,28 +210,37 @@ def self_attention(p: dict, x: jax.Array, cfg: ArchConfig, *,
                                               (0, 0, slot, 0))
             new_cache = {"k": ck, "v": cv}
             if stepwise:
-                out = _step_attention(q, ck, cv, kt, vt, cache_pos)
+                out = _step_attention(q, ck, cv, kt, vt, cache_pos,
+                                      scale=scale)
             elif window is None:
                 # The einsum-softmax form partitions cleanly when the
                 # cache seq dim is sharded (long-context SP), unlike a
                 # kv-block scan.
                 kv_len = jnp.minimum(cache_pos + q.shape[2], s_cache)
-                out = _cache_attention(q, ck, cv, kv_len, causal=True)
+                out = _cache_attention(q, ck, cv, kv_len, causal=True,
+                                       scale=scale)
             else:
                 # Ring buffer: every populated slot is within the window
                 # by construction (cache length == window).
-                out = _ring_attention(q, ck, cv, cache_pos, s_cache)
+                out = _ring_attention(q, ck, cv, cache_pos, s_cache,
+                                      scale=scale)
     out = cm.linear(p["wo"], _merge_heads(out), cd)
     return out, new_cache
 
 
-def _cache_attention(q, ck, cv, kv_len, *, causal: bool):
+def _scaled(q, scale):
+    """q in float32 times the softmax scale (None: 1/sqrt(head_dim))."""
+    qf = q.astype(jnp.float32)
+    return qf / (q.shape[-1] ** 0.5) if scale is None else qf * scale
+
+
+def _cache_attention(q, ck, cv, kv_len, *, causal: bool, scale=None):
     """Einsum-softmax attention over a (possibly sharded) KV cache.
     q: (B, Hq, Sq, D); ck/cv: (B, Hkv, S, D); kv_len: valid slot count."""
     b, hq, sq, d = q.shape
     hkv, s_cache = ck.shape[1], ck.shape[2]
     group = hq // hkv
-    qg = q.reshape(b, hkv, group, sq, d).astype(jnp.float32) / (d ** 0.5)
+    qg = _scaled(q.reshape(b, hkv, group, sq, d), scale)
     s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, ck.astype(jnp.float32))
     kj = jnp.arange(s_cache)[None, :]
     mask = kj < kv_len
@@ -241,7 +253,7 @@ def _cache_attention(q, ck, cv, kv_len, *, causal: bool):
     return out.reshape(b, hq, sq, d).astype(q.dtype)
 
 
-def _step_attention(q, ck, cv, k, v, pos, *, replaced=None):
+def _step_attention(q, ck, cv, k, v, pos, *, replaced=None, scale=None):
     """Einsum-softmax attention of each query over the cache rows before
     its position, joined in one softmax with its own key and value: the
     decode form.
@@ -256,7 +268,7 @@ def _step_attention(q, ck, cv, k, v, pos, *, replaced=None):
     b, hq, sq, d = q.shape
     hkv, s_cache = ck.shape[1], ck.shape[2]
     group = hq // hkv
-    qg = q.reshape(b, hkv, group, sq, d).astype(jnp.float32) / (d ** 0.5)
+    qg = _scaled(q.reshape(b, hkv, group, sq, d), scale)
     s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, ck.astype(jnp.float32))
     kj = jnp.arange(s_cache)[None, :]
     mask = kj < pos + jnp.arange(sq)[:, None]
@@ -274,7 +286,7 @@ def _step_attention(q, ck, cv, k, v, pos, *, replaced=None):
     return out.reshape(b, hq, sq, d).astype(q.dtype)
 
 
-def _ring_attention(q, ck, cv, cache_pos, s_cache):
+def _ring_attention(q, ck, cv, cache_pos, s_cache, *, scale=None):
     """Decode attention over a ring-buffer SWA cache: softmax over the
     populated slots (≤ window of them); permutation-invariant since RoPE
     phases were applied at write time."""
@@ -282,7 +294,7 @@ def _ring_attention(q, ck, cv, cache_pos, s_cache):
     hkv = ck.shape[1]
     group = hq // hkv
     n_valid = jnp.minimum(cache_pos + sq, s_cache)
-    qg = q.reshape(b, hkv, group, sq, d).astype(jnp.float32) / (d ** 0.5)
+    qg = _scaled(q.reshape(b, hkv, group, sq, d), scale)
     s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, ck.astype(jnp.float32))
     mask = jnp.arange(s_cache)[None, :] < n_valid
     s = jnp.where(mask[None, None, None], s, -jnp.inf)

@@ -6,9 +6,11 @@ mLSTM (both are gated-linear-attention recurrences).
 
 computed chunk-parallel (the paper's chunking insight applied to the
 sequence dimension): intra-chunk quadratic term + inter-chunk state
-S (B, H, N, P) carried by a lax.scan over chunks.  Per-chunk max
-stabilisation keeps the exponentials in fp32 range; chunk length 64
-bounds exp(local-cum) underflow.
+S (B, H, N, P) carried by a lax.scan over chunks.  Every exponential is
+of a sum of log-decays over a span that ends where it is read, plus the
+source's log-gain, so none exceeds ``exp(gain)`` however fast a head
+decays (a factorised ``exp(cum_i) * exp(-cum_j)`` would underflow and
+overflow at a realistic Mamba-2 decay over a 64-token chunk).
 
 Decode uses the O(1) recurrent step (``gla_step``).
 """
@@ -43,21 +45,20 @@ def chunked_gla(q: jax.Array, k: jax.Array, v: jax.Array,
 
     lcum = jnp.cumsum(ld, axis=2)                  # within-chunk cumsum
     total = lcum[:, :, -1, :]                      # (b, nc, h)
-    a = g - lcum                                   # exponent "source" term
-    m = jax.lax.stop_gradient(jnp.max(a, axis=2, keepdims=True))
-    ks = kf * jnp.exp(a - m)[..., None]            # stabilised keys
+
+    # intra-chunk: att[i, j] = (q_i · k_j) exp(cum_i - cum_j + g_j), i >= j
+    mask = jnp.tril(jnp.ones((chunk, chunk), bool))[None, None, :, :, None]
+    seg = lcum[:, :, :, None, :] - lcum[:, :, None, :, :] \
+        + g[:, :, None, :, :]                      # (b, nc, i, j, h)
+    w = jnp.exp(jnp.where(mask, seg, -jnp.inf))
+    att = jnp.einsum("bcihn,bcjhn->bchij", qf, kf) \
+        * w.transpose(0, 1, 4, 2, 3)
+    y_intra = jnp.einsum("bchij,bcjhp->bcihp", att, vf)
+
+    # local end-of-chunk states: S_loc = sum_j exp(total - cum_j + g_j) k_j ⊗ v_j
+    ks = kf * jnp.exp(total[:, :, None, :] - lcum + g)[..., None]
+    s_loc = jnp.einsum("bcjhn,bcjhp->bchnp", ks, vf)
     qd = qf * jnp.exp(lcum)[..., None]             # decayed queries
-
-    # intra-chunk: att[i, j] = (qd_i · ks_j) masked to i >= j, times exp(m)
-    att = jnp.einsum("bcihn,bcjhn->bchij", qd, ks)
-    mask = jnp.tril(jnp.ones((chunk, chunk), bool))
-    att = jnp.where(mask[None, None, None], att, 0.0)
-    y_intra = jnp.einsum("bchij,bcjhp->bcihp", att, vf) \
-        * jnp.exp(m)[..., None]                    # m: (b, nc, 1, h)
-
-    # local end-of-chunk states: S_loc = exp(total + m) * sum_j ks_j ⊗ v_j
-    s_loc = jnp.einsum("bcjhn,bcjhp->bchnp", ks, vf) \
-        * jnp.exp(total + m[:, :, 0, :])[..., None, None]
 
     # scan chunks: S_c = exp(total_c) * S_{c-1} + S_loc_c
     decay_c = jnp.exp(total)                       # (b, nc, h)

@@ -56,6 +56,9 @@ def _block_init(key, cfg: ArchConfig, kind: str) -> dict:
         p["proj"] = cm.linear_init(kg(), d, d, dtype=dt)
     elif kind == "mamba2":
         p["mamba"] = mamba2.init(kg(), cfg)
+        if cfg.mamba_ffn:
+            p["ln2"] = cm.rmsnorm_init(d, dt)
+            p["ffn"] = ffn.init(kg(), cfg)
     elif kind == "mlstm":
         p["mlstm"] = xlstm.mlstm_init(kg(), cfg)
     elif kind == "slstm":
@@ -107,6 +110,21 @@ def init_params(key, cfg: ArchConfig) -> dict:
 # block application
 # ---------------------------------------------------------------------------
 
+def _residual(x, y, cfg: ArchConfig):
+    """``x + y``, the branch ``y`` scaled by the residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
+def _embed(params, tokens, cfg: ArchConfig):
+    """The tokens' embeddings times the embedding multiplier."""
+    x = cm.embed(params["embed"], tokens, jnp.dtype(cfg.compute_dtype))
+    if cfg.embed_multiplier != 1.0:
+        x = x * cfg.embed_multiplier
+    return flags.constrain(x)
+
+
 def _apply_shared(shared: dict, proj: dict, x, cfg: ArchConfig, *,
                   positions, window, impl, cache=None, cache_pos=None,
                   stepwise=False):
@@ -124,8 +142,9 @@ def _apply_shared(shared: dict, proj: dict, x, cfg: ArchConfig, *,
 
 def _apply_block(kind: str, p: dict, x, cfg: ArchConfig, *,
                  positions, window, impl, shared=None, frontend_feats=None,
-                 cache=None, cache_pos=None, stepwise=False):
-    """Returns (x, aux_loss, new_cache)."""
+                 cache=None, cache_pos=None, stepwise=False, length=None):
+    """Returns (x, aux_loss, new_cache).  ``length``: real tokens of a
+    padded chunk (recurrent layers leave their state as those left it)."""
     cd = jnp.dtype(cfg.compute_dtype)
     aux = jnp.zeros((), jnp.float32)
     new_cache = None
@@ -136,24 +155,24 @@ def _apply_block(kind: str, p: dict, x, cfg: ArchConfig, *,
                 p["attn"], h, cfg, positions=positions, window=window,
                 impl=impl, cache=cache, cache_pos=cache_pos,
                 stepwise=stepwise)
-        x = x + a
+        x = _residual(x, a, cfg)
         h = cm.rmsnorm(p["ln2"], x, cfg.norm_eps)
         with jax.named_scope("mlp"):
             if cfg.n_experts:
                 y, aux = moe.apply(p["moe"], h, cfg)
-                x = x + y
+                x = _residual(x, y, cfg)
             elif cfg.d_ff:
-                x = x + ffn.apply(p["ffn"], h, cfg)
+                x = _residual(x, ffn.apply(p["ffn"], h, cfg), cfg)
     elif kind == "cross_attn":
         h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
         feats = frontend_feats
         if feats is None:
             raise ValueError("cross_attn block needs frontend_feats")
-        x = x + attention.cross_attention(p["xattn"], h, feats.astype(cd),
-                                          cfg, impl=impl)
+        x = _residual(x, attention.cross_attention(
+            p["xattn"], h, feats.astype(cd), cfg, impl=impl), cfg)
         h = cm.rmsnorm(p["ln2"], x, cfg.norm_eps)
         if cfg.d_ff:
-            x = x + ffn.apply(p["ffn"], h, cfg)
+            x = _residual(x, ffn.apply(p["ffn"], h, cfg), cfg)
     elif kind == "shared_attn":
         x, new_cache = _apply_shared(
             shared, p["proj"], x, cfg, positions=positions,
@@ -161,16 +180,22 @@ def _apply_block(kind: str, p: dict, x, cfg: ArchConfig, *,
             stepwise=stepwise)
     elif kind == "mamba2":
         h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        y, new_cache = mamba2.apply(p["mamba"], h, cfg, state=cache)
-        x = x + y
+        with jax.named_scope("ssm"):
+            y, new_cache = mamba2.apply(p["mamba"], h, cfg, state=cache,
+                                        length=length)
+        x = _residual(x, y, cfg)
+        if cfg.mamba_ffn:
+            h = cm.rmsnorm(p["ln2"], x, cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                x = _residual(x, ffn.apply(p["ffn"], h, cfg), cfg)
     elif kind == "mlstm":
         h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
         y, new_cache = xlstm.mlstm_apply(p["mlstm"], h, cfg, state=cache)
-        x = x + y
+        x = _residual(x, y, cfg)
     elif kind == "slstm":
         h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
         y, new_cache = xlstm.slstm_apply(p["slstm"], h, cfg, state=cache)
-        x = x + y
+        x = _residual(x, y, cfg)
     else:
         raise ValueError(kind)
     return x, aux, new_cache
@@ -181,8 +206,12 @@ def _logits(params, cfg: ArchConfig, x):
     cd = jnp.dtype(cfg.compute_dtype)
     x = cm.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if cfg.tie_embeddings:
-        return x @ params["embed"]["table"].astype(cd).T
-    return cm.linear(params["lm_head"], x, cd)
+        logits = x @ params["embed"]["table"].astype(cd).T
+    else:
+        logits = cm.linear(params["lm_head"], x, cd)
+    if cfg.logits_divisor != 1.0:
+        logits = logits / cfg.logits_divisor
+    return logits
 
 
 # ---------------------------------------------------------------------------
@@ -196,9 +225,8 @@ def forward(params: dict, batch: dict, cfg: ArchConfig, *,
     Returns (logits (B, S, V), aux_loss)."""
     tokens = batch["tokens"]
     bsz, s = tokens.shape
-    cd = jnp.dtype(cfg.compute_dtype)
     window = window if window is not None else cfg.attn_window
-    x = flags.constrain(cm.embed(params["embed"], tokens, cd))
+    x = _embed(params, tokens, cfg)
     positions = jnp.arange(s)
     feats = batch.get("frontend_feats")
     period = len(cfg.block_pattern)
@@ -264,7 +292,7 @@ def _layer_params(params, cfg: ArchConfig, layer: int):
 def forward_cached(params: dict, tokens: jax.Array, caches: list, pos,
                    cfg: ArchConfig, *, window: int | None = None,
                    frontend_feats=None, logit_index=None,
-                   all_logits: bool = False
+                   all_logits: bool = False, length=None
                    ) -> tuple[jax.Array, list]:
     """tokens: (B, L_new); caches: per-layer state list; pos: scalar count
     of tokens already cached.  Returns (logits of one position, caches):
@@ -275,11 +303,14 @@ def forward_cached(params: dict, tokens: jax.Array, caches: list, pos,
     V)``) — the speculative verify needs the model's prediction after
     each draft token in one batched forward, and each position then
     attends as a one-token decode step would, so the two agree token for
-    token."""
+    token.  ``length`` (int or traced scalar) counts the real tokens of
+    a padded chunk: a recurrent layer's state is left as they left it,
+    so the pad tokens change nothing (attention masks them by
+    position)."""
     logits, updates = forward_step(
         params, tokens, caches, pos, cfg, window=window,
         frontend_feats=frontend_feats, logit_index=logit_index,
-        all_logits=all_logits)
+        all_logits=all_logits, length=length)
     return logits, commit(caches, updates)
 
 
@@ -313,16 +344,16 @@ def commit(caches: list, updates: list, keep=None) -> list:
 def forward_step(params: dict, tokens: jax.Array, caches: list, pos,
                  cfg: ArchConfig, *, window: int | None = None,
                  frontend_feats=None, logit_index=None,
-                 all_logits: bool = False) -> tuple[jax.Array, list]:
+                 all_logits: bool = False, length=None
+                 ) -> tuple[jax.Array, list]:
     """``forward_cached`` without the cache writes: returns (logits,
     updates).  For a one-token step (``L_new == 1``) an attention layer's
     update is ``{"k": KVWrite, "v": KVWrite}`` — the token and the row it
     goes to — so a caller holding a donated pool writes only that token;
     otherwise (a prefill chunk, a recurrent state) it is the layer's new
     cache or state, as ``forward_cached`` returns it."""
-    cd = jnp.dtype(cfg.compute_dtype)
     window = window if window is not None else cfg.attn_window
-    x = flags.constrain(cm.embed(params["embed"], tokens, cd))
+    x = _embed(params, tokens, cfg)
     l_new = tokens.shape[1]
     positions = pos + jnp.arange(l_new)
     kinds = cfg.layer_kinds()
@@ -333,7 +364,8 @@ def forward_step(params: dict, tokens: jax.Array, caches: list, pos,
             kind, p, x, cfg, positions=positions, window=window,
             impl="chunked", shared=params.get("shared_block"),
             frontend_feats=frontend_feats,
-            cache=caches[layer], cache_pos=pos, stepwise=all_logits)
+            cache=caches[layer], cache_pos=pos, stepwise=all_logits,
+            length=length)
         x = flags.constrain(x)
         updates.append(update)
     if all_logits:

@@ -1,9 +1,12 @@
 """Mamba2 (SSD) block, built on the chunked gated-linear-attention engine
-(models/gla.py) — the SSD duality: q=C, k=B, v=x, log-decay = Δ·A,
-log-gain = log Δ.
+(models/gla.py) — the SSD duality: q=C, k=B, v=Δ·x, log-decay = Δ·A.
 
-Parallel (train/prefill) path: chunked_gla.  Decode path: O(1) recurrent
-``gla_step`` + depthwise-conv ring state.
+A prompt or a prefill chunk runs through ``chunked_gla``, from a carried
+state when one is given; the one-token decode step through the O(1)
+recurrent ``gla_step``.  The depthwise causal conv carries its last
+``conv_width - 1`` inputs.  The output is normalised by the gated RMSNorm
+(``rmsnorm(y · silu(z)) · g``) before ``out_proj``, as Mamba-2 publishes
+it.
 """
 from __future__ import annotations
 
@@ -29,54 +32,64 @@ def init(key, cfg: ArchConfig) -> dict:
         "a_log": jnp.zeros((h,), dt),               # A = -exp(a_log) = -1
         "dt_bias": jnp.zeros((h,), dt),
         "d_skip": jnp.ones((h,), dt),
+        "norm": cm.rmsnorm_init(di, dt),            # gated RMSNorm gain
         "out_proj": cm.linear_init(kg(), di, d, dtype=dt),
     }
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
-                 state: jax.Array | None = None):
+                 state: jax.Array | None = None, length=None):
     """Depthwise causal conv1d.  x: (B, L, C); w: (W, C).
-    ``state``: (B, W-1, C) carry-in; returns (out, new_state)."""
+    ``state``: (B, W-1, C) carry-in; returns (out, new_state), the new
+    state being the last W-1 inputs up to ``length`` (int or traced
+    scalar; None: all L)."""
     bsz, l, c = x.shape
     wlen = w.shape[0]
     if state is None:
         state = jnp.zeros((bsz, wlen - 1, c), x.dtype)
-    ext = jnp.concatenate([state, x], axis=1)
+    ext = jnp.concatenate([state.astype(x.dtype), x], axis=1)
     out = jnp.zeros_like(x, dtype=jnp.float32)
     for i in range(wlen):
         out = out + ext[:, i:i + l].astype(jnp.float32) \
             * w[i].astype(jnp.float32)
-    new_state = ext[:, -(wlen - 1):] if wlen > 1 else state
+    if wlen == 1:
+        new_state = state
+    elif length is None:
+        new_state = ext[:, l:]
+    else:
+        new_state = jax.lax.dynamic_slice_in_dim(ext, length, wlen - 1, 1)
     return (jax.nn.silu(out + b.astype(jnp.float32))).astype(x.dtype), new_state
 
 
 def _project(p, xin, cfg: ArchConfig):
-    di, n, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
+    di, n = cfg.d_inner, cfg.ssm_state
     cd = jnp.dtype(cfg.compute_dtype)
     zxbcdt = cm.linear(p["in_proj"], xin, cd)
     z = zxbcdt[..., :di]
-    x = zxbcdt[..., di:2 * di]
-    b_ = zxbcdt[..., 2 * di:2 * di + n]
-    c_ = zxbcdt[..., 2 * di + n:2 * di + 2 * n]
+    xbc = zxbcdt[..., di:2 * di + 2 * n]
     dt_raw = zxbcdt[..., 2 * di + 2 * n:]
-    return z, x, b_, c_, dt_raw
+    return z, xbc, dt_raw
 
 
 def apply(p: dict, xin: jax.Array, cfg: ArchConfig, *,
-          state: dict | None = None) -> tuple[jax.Array, dict | None]:
-    """xin: (B, L, d).  state (decode): {"ssm": (B,H,N,P), "conv": (B,W-1,C)}.
+          state: dict | None = None, length=None
+          ) -> tuple[jax.Array, dict]:
+    """xin: (B, L, d).  state: {"ssm": (B,H,N,P), "conv": (B,W-1,C)}, the
+    state after the tokens before this chunk (None: a fresh sequence).
 
-    Parallel path when state is None; recurrent when a state is given
-    (then L is the number of new tokens, scanned one by one)."""
+    ``length`` (int or traced scalar; None: L) counts the real tokens of
+    the chunk; the positions after it are padding and leave the state
+    exactly as the real tokens left it — their Δ is 0, so their decay is
+    exp(0) and their input term 0, and the conv state is taken at
+    ``length``.  Their outputs are garbage for the caller to drop."""
     di, n, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
     hd = cfg.ssm_head_dim
     cd = jnp.dtype(cfg.compute_dtype)
-    z, x, b_, c_, dt_raw = _project(p, xin, cfg)
+    z, xbc, dt_raw = _project(p, xin, cfg)
 
-    conv_in = jnp.concatenate([x, b_, c_], axis=-1)
     conv_state = None if state is None else state["conv"]
-    conv_out, new_conv = _causal_conv(conv_in, p["conv_w"], p["conv_b"],
-                                      conv_state)
+    conv_out, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"],
+                                      conv_state, length)
     x = conv_out[..., :di]
     b_ = conv_out[..., di:di + n]
     c_ = conv_out[..., di + n:]
@@ -85,37 +98,33 @@ def apply(p: dict, xin: jax.Array, cfg: ArchConfig, *,
     a = -jnp.exp(p["a_log"].astype(jnp.float32))          # (h,)
     delta = jax.nn.softplus(dt_raw.astype(jnp.float32)
                             + p["dt_bias"].astype(jnp.float32))  # (B,L,h)
+    if length is not None:
+        delta = jnp.where((jnp.arange(l) < length)[None, :, None], delta, 0.0)
     log_decay = delta * a                                  # (B,L,h)
-    log_gain = jnp.log(delta + 1e-9)
 
-    xh = x.reshape(bsz, l, h, hd)
+    xh = x.reshape(bsz, l, h, hd).astype(jnp.float32)
+    vh = xh * delta[..., None]                             # Δ·x
     qh = jnp.broadcast_to(c_[:, :, None, :], (bsz, l, h, n))
     kh = jnp.broadcast_to(b_[:, :, None, :], (bsz, l, h, n))
+    s0 = None if state is None else state["ssm"]
 
-    if state is None:
+    if s0 is not None and l == 1:
+        y, s_final = gla.gla_step(qh[:, 0], kh[:, 0], vh[:, 0],
+                                  log_decay[:, 0], None, s0)
+        y = y[:, None]
+    else:
         pad = (-l) % gla.DEFAULT_CHUNK
         if pad:
             padf = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-            qh, kh, xh = padf(qh), padf(kh), padf(xh)
-            log_decay, log_gain = padf(log_decay), padf(log_gain)
-        y, s_final = gla.chunked_gla(qh, kh, xh, log_decay, log_gain)
+            qh, kh, vh, log_decay = padf(qh), padf(kh), padf(vh), padf(log_decay)
+        y, s_final = gla.chunked_gla(qh, kh, vh, log_decay,
+                                     initial_state=s0)
         y = y[:, :l]
-        new_state = {"ssm": s_final, "conv": new_conv}
-    else:
-        s = state["ssm"]
-        ys = []
-        for t in range(l):
-            yt, s = gla.gla_step(qh[:, t], kh[:, t], xh[:, t],
-                                 log_decay[:, t], log_gain[:, t], s)
-            ys.append(yt)
-        y = jnp.stack(ys, axis=1)
-        new_state = {"ssm": s, "conv": new_conv}
 
-    y = y + p["d_skip"].astype(jnp.float32)[None, None, :, None] \
-        * xh[:, :l].astype(jnp.float32)
-    y = y.reshape(bsz, l, di).astype(cd)
-    y = y * jax.nn.silu(z.astype(jnp.float32)).astype(cd)
-    return cm.linear(p["out_proj"], y, cd), new_state
+    y = y + p["d_skip"].astype(jnp.float32)[None, None, :, None] * xh
+    gated = y.reshape(bsz, l, di) * jax.nn.silu(z.astype(jnp.float32))
+    y = cm.rmsnorm(p["norm"], gated, cfg.norm_eps).astype(cd)
+    return cm.linear(p["out_proj"], y, cd), {"ssm": s_final, "conv": new_conv}
 
 
 def init_state(cfg: ArchConfig, batch: int, dtype) -> dict:

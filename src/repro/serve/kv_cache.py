@@ -41,6 +41,7 @@ import numpy as np
 from ..configs.base import ArchConfig
 from ..core import strict
 from ..models import lm
+from . import spans
 
 _ATTN_KINDS = ("attn", "shared_attn")
 
@@ -52,6 +53,42 @@ def _tree_map(fn, tree):
 
 def _maybe(fn):
     return lambda x: None if x is None else fn(x)
+
+
+class _StateReset:
+    """Zeroes one slot's rows of every recurrent (non-attention) layer,
+    under the span ``serve.state_reset``: one jitted update with those
+    layers' states donated, as ``write_slot`` writes a row (an eager
+    ``.at[slot].set(0)`` per leaf would copy each leaf's whole pool
+    array).  A pool without recurrent layers does nothing."""
+
+    def __init__(self, kinds, caches: list, shardings):
+        self.layers = tuple(i for i, (k, c) in enumerate(
+            zip(kinds, caches, strict=True))
+            if c is not None and k not in _ATTN_KINDS)
+        self._shardings = None if shardings is None \
+            else [shardings[i] for i in self.layers]
+        self._jit = None
+
+    def __call__(self, caches: list, slot: int) -> list:
+        if not self.layers:
+            return caches
+        with spans.span("serve.state_reset", layers=len(self.layers)):
+            if self._jit is None:
+                def zero(states, s):
+                    return jax.tree.map(
+                        lambda x: jax.lax.dynamic_update_slice(
+                            x, jnp.zeros((1,) + x.shape[1:], x.dtype),
+                            (s,) + (0,) * (x.ndim - 1)), states)
+
+                self._jit = jax.jit(zero, donate_argnums=0,
+                                    out_shardings=self._shardings)
+            new = self._jit([caches[i] for i in self.layers],
+                            jnp.int32(slot))
+            out = list(caches)
+            for i, c in zip(self.layers, new, strict=True):
+                out[i] = c
+            return out
 
 
 class SlotOverflowError(ValueError):
@@ -113,6 +150,8 @@ class SlotKVCachePool:
                 mesh, sharding_lib.cache_specs(cfg, mesh, n_slots, max_len))
             self.caches = jax.device_put(self.caches, self.shardings)
         self._layout_sig = _layout(self.caches)
+        self._reset = _StateReset(cfg.layer_kinds(), self.caches,
+                                  self.shardings)
         self.allocations = 1            # init_caches calls ever made
         self._free = list(range(n_slots - 1, -1, -1))
         self.positions = [0] * n_slots  # tokens cached per slot (host side)
@@ -168,15 +207,7 @@ class SlotKVCachePool:
 
     def _zero_slot_states(self, slot: int) -> None:
         """Zero the non-attention (recurrent) state rows of ``slot``."""
-
-        def zero_row(kind: str, cache):
-            if cache is None or kind in ("attn", "shared_attn"):
-                return cache
-            return _tree_map(_maybe(lambda x: x.at[slot].set(0)), cache)
-
-        self.caches = [zero_row(kind, c) for kind, c in
-                       zip(self.cfg.layer_kinds(), self.caches,
-                           strict=True)]
+        self.caches = self._reset(self.caches, slot)
 
     # -- slot I/O ------------------------------------------------------------
     def read_slot(self, slot: int):
@@ -392,6 +423,7 @@ class PagedKVCachePool:
                     cfg, mesh, n_slots, max_len))
             self.caches = jax.device_put(self.caches, self.shardings)
         self._layout_sig = _layout(self.caches)
+        self._reset = _StateReset(self.kinds, self.caches, self.shardings)
         self.allocations = 1            # init_caches calls ever made
         self._free = list(range(n_slots - 1, -1, -1))
         self.positions = [0] * n_slots
@@ -565,13 +597,7 @@ class PagedKVCachePool:
         self._free.append(slot)
 
     def _zero_slot_states(self, slot: int) -> None:
-        def zero_row(kind: str, cache):
-            if cache is None or kind in _ATTN_KINDS:
-                return cache
-            return _tree_map(_maybe(lambda x: x.at[slot].set(0)), cache)
-
-        self.caches = [zero_row(kind, c) for kind, c in
-                       zip(self.kinds, self.caches, strict=True)]
+        self.caches = self._reset(self.caches, slot)
 
     # -- page allocation / refcounts -----------------------------------------
     def _unref_page(self, pid: int) -> None:

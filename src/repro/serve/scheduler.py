@@ -276,12 +276,14 @@ class ServeScheduler:
         self.clock = clock
         self.chunk_buckets = tuple(sorted(set(int(b) for b in chunk_buckets
                                               if b > 0))) or (max_len,)
-        # Padding prefill chunks to a bucket is only sound when every
-        # layer masks by position: recurrent (SSM/xLSTM) states would
-        # absorb the pad tokens, and ring (SWA) writes could wrap over
+        # Padding prefill chunks to a bucket is only sound where every
+        # layer leaves the pad tokens out: attention masks them by
+        # position, and a Mamba-2 layer is told the chunk's real length
+        # and leaves its state as the real tokens left it.  xLSTM states
+        # would absorb the pads, and ring (SWA) writes could wrap over
         # live entries — those archs run exact-size chunks instead.
-        self._pad_ok = self.window is None and kinds <= {"attn",
-                                                         "shared_attn"}
+        self._pad_ok = self.window is None and kinds <= {
+            "attn", "shared_attn", "mamba2"}
         self.prefill_profile, self.decode_profile = serve_profiles(cfg)
         # Workload keys carry the model's shape, not just its name:
         # cfg.reduced() keeps the name, and a persisted t_iter smoothed on
@@ -995,7 +997,8 @@ class ServeScheduler:
                                         or flags.KERNEL_TUNER):
                     return lm.forward_cached(params, piece, row_caches, pos,
                                              cfg, window=window,
-                                             logit_index=last)
+                                             logit_index=last,
+                                             length=last + 1)
 
             fn = jax.jit(prefill_chunk)
             self._prefill_jit[length] = fn
@@ -1057,7 +1060,7 @@ class ServeScheduler:
             # for the feedback loop, and an async jit dispatch would
             # record microseconds of launch cost as the chunk's t_iter.
             with spans.span("serve.prefill.chunk", parent=span_id,
-                            tokens=padded) as run:
+                            tokens=padded, pad=padded - step) as run:
                 out = jax.block_until_ready(  # repro-lint: disable=RL002
                     self._prefill_step(padded)(
                         params, row, piece[None], jnp.int32(req.prefilled),

@@ -8,7 +8,7 @@ case compiles at a block the planners or the autotuner can pick — the
 analytic prior and the largest VMEM-budgeted candidate — and checks that
 the program holds the Mosaic kernel (``tpu_custom_call``).  The fused
 decode step is compiled too, for what the TPU's layouts do to its loop
-body.
+body, and at granite-4.0-h-micro's full size for the memory it needs.
 """
 import os
 
@@ -155,3 +155,43 @@ def test_fused_decode_step_writes_pool_in_place(one_chip, dtype):
     shapes = {tuple(x.shape) for x in jax.tree.leaves(caches)}
     assert hlo_audit.pool_shaped_ops(bodies, shapes) == \
         {"select": 0, "copy": 0}
+
+
+def test_granite_pool_fits_one_chip(one_chip):
+    """granite-4.0-h-micro at its published 40 layers, on the pool of its
+    benchmark cell (32 slots x 4,096): the fused decode step and the
+    largest prefill bucket compile for a v5e, and the decode step, the
+    weights and the pool leave at least 1.5 GiB of its 15.75 GiB free —
+    the slot count ``bench/configs/granite-4.0-h-micro.json`` states."""
+    from repro.models import lm
+    from repro.serve.scheduler import DEFAULT_CHUNK_BUCKETS
+
+    cfg = get_config("granite-4.0-h-micro")
+    slots, max_len = 32, 4096
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def ints(shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(lambda: init_caches(cfg, slots, max_len)))
+    mem = make_fused_decode_step(cfg).lower(
+        params, pool, ints((slots,)), ints((slots,)), ints((slots,))
+    ).compile().memory_analysis()
+    peak = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert peak <= (15.75 - 1.5) * 2**30, peak / 2**30
+
+    def prefill_chunk(params, row, piece, pos, last):
+        return lm.forward_cached(params, piece, row, pos, cfg,
+                                 logit_index=last, length=last + 1)
+
+    row = on_chip(jax.eval_shape(lambda: init_caches(cfg, 1, max_len)))
+    mem = jax.jit(prefill_chunk).lower(
+        params, row, ints((1, DEFAULT_CHUNK_BUCKETS[-1])), ints(()), ints(())
+    ).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 2**30

@@ -81,3 +81,47 @@ def test_unroll_flag_equivalence():
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
                                rtol=1e-5, atol=1e-5)
+
+
+def _recurrence(q, k, v, ld, g, state):
+    ys = []
+    for t in range(q.shape[1]):
+        yt, state = gla.gla_step(q[:, t], k[:, t], v[:, t], ld[:, t],
+                                 None if g is None else g[:, t], state)
+        ys.append(yt)
+    return jnp.stack(ys, 1), state
+
+
+def test_carried_state_with_masked_pads_matches_recurrence():
+    """A chunk that starts from a carried state and ends in pads (zero
+    input, zero log-decay) gives the sequential recurrence's outputs on
+    its real tokens and leaves the state where they left it."""
+    q, k, v, ld, g = make(2, 48, 3, 8, 4)
+    s0 = jnp.asarray(RS.randn(2, 3, 8, 4).astype(np.float32))
+    real = 29
+    keep = (jnp.arange(48) < real)[None, :, None]
+    v_pad = jnp.where(keep[..., None], v, 0.0)
+    ld_pad = jnp.where(keep, ld, 0.0)
+    y, s = gla.chunked_gla(q, k, v_pad, ld_pad, g, chunk=16,
+                           initial_state=s0)
+    y_ref, s_ref = _recurrence(q[:, :real], k[:, :real], v[:, :real],
+                               ld[:, :real], g[:, :real], s0)
+    np.testing.assert_allclose(np.asarray(y[:, :real]), np.asarray(y_ref),
+                               rtol=3e-4, atol=3e-4)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
+                               rtol=3e-4, atol=3e-4)
+
+
+def test_fast_decay_stays_exact():
+    """Heads that decay by e^-20 a token (a Mamba-2 head with A = 16 and
+    Δ past 1): every exponential stays in range, so the chunked form
+    still equals the recurrence instead of underflowing to zero."""
+    q, k, v, _, _ = make(1, 64, 2, 4, 4)
+    ld = jnp.full((1, 64, 2), -20.0).at[:, :, 1].set(-0.01)
+    y, s = gla.chunked_gla(q, k, v, ld, None, chunk=64)
+    y_ref, s_ref = _recurrence(q, k, v, ld, None, jnp.zeros((1, 2, 4, 4)))
+    assert np.all(np.isfinite(np.asarray(y)))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=3e-4, atol=3e-4)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
+                               rtol=3e-4, atol=3e-4)
